@@ -13,6 +13,8 @@ import os
 import sys
 import traceback
 
+from repro.utils import enable_compile_cache
+
 SUITES = ("startup", "latency", "producer_throughput", "processing_throughput",
           "elasticity", "predictive", "kernel_bench", "hotpath")
 
@@ -41,6 +43,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, help="comma-separated suite names")
     args = ap.parse_args()
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     print("name,us_per_call,derived")

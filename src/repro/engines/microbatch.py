@@ -234,13 +234,17 @@ class MicroBatchStream:
         """Re-shard live state onto a changed device set. Blocks until any
         in-flight batch commits its state, so the reshard never races it:
         the state lock serializes against the batch loop, and sync_fn drains
-        the processor's async double-buffer before buffers move devices."""
+        the processor's async double-buffer before buffers move devices.
+
+        ``on_rescale(devices)`` returns the new state, or a function that
+        maps the current state to it (the MASA apps' reshard hooks)."""
         if self.on_rescale is None:
             return
         with self._state_lock:
             if self.sync_fn is not None:
                 self.sync_fn()
-            self.state = self.on_rescale(devices)
+            new = self.on_rescale(devices)
+            self.state = new(self.state) if callable(new) else new
 
     # ---- failure recovery -----------------------------------------------------
 

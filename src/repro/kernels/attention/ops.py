@@ -1,9 +1,14 @@
-"""Jitted wrapper for the flash-attention kernel ((B,S,H,hd) layout in/out)."""
+"""Jitted wrapper for the flash-attention kernel ((B,S,H,hd) layout in/out).
+
+``interpret`` states the kernel mode; ``None`` derives it in one place
+(:func:`repro.kernels.kernel_interpret`): native on a TPU, interpret mode
+only where the CPU tests run."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import kernel_interpret
 from repro.kernels.attention.kernel import flash_attention_pallas
 from repro.kernels.attention.ref import attention_ref
 
@@ -20,7 +25,7 @@ def flash_attention(
     block_q: int = 256,
     block_kv: int = 512,
     use_kernel: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     qt = q.swapaxes(1, 2)  # (B, H, Sq, hd)
     kt = k.swapaxes(1, 2)
@@ -33,7 +38,8 @@ def flash_attention(
                 "(runtime/sharded_attention.py) before calling the kernel"
             )
         out = flash_attention_pallas(
-            qt, kt, vt, causal=causal, block_q=block_q, block_kv=block_kv, interpret=interpret
+            qt, kt, vt, causal=causal, block_q=block_q, block_kv=block_kv,
+            interpret=kernel_interpret(interpret),
         )
     else:
         out = attention_ref(qt, kt, vt, causal=causal)

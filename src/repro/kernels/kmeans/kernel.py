@@ -19,8 +19,9 @@ def _assign_kernel(p_ref, c_ref, c2_ref, labels_ref, dist_ref):
     c = c_ref[...].astype(jnp.float32)  # (K, D)
     c2 = c2_ref[...]  # (1, K)
     cross = jax.lax.dot_general(
-        p, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (BN, K) on the MXU
+        p, c, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )  # (BN, K) on the MXU, f32-exact (a single bf16 pass would move labels)
     p2 = jnp.sum(p * p, axis=1, keepdims=True)  # (BN, 1)
     d2 = p2 - 2.0 * cross + c2  # (BN, K)
     labels_ref[...] = jnp.argmin(d2, axis=1).astype(jnp.int32)

@@ -4,6 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import kernel_interpret
 from repro.kernels.kmeans.kernel import assign_pallas
 from repro.kernels.kmeans.ref import assign_ref, update_ref, update_scatter
 
@@ -18,10 +19,13 @@ def _pad_to(x: jax.Array, m: int, axis: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-def assign(points, centroids, *, use_kernel: bool = False, block_n: int = 1024, interpret: bool = True):
-    """K-Means assignment. ``use_kernel`` selects the Pallas TPU kernel
-    (``interpret=True`` executes it on CPU for validation); otherwise the
-    jnp reference (which XLA also fuses well)."""
+def assign(points, centroids, *, use_kernel: bool = False, block_n: int = 1024,
+           interpret: bool | None = None):
+    """K-Means assignment. ``use_kernel`` selects the Pallas TPU kernel,
+    otherwise the jnp reference (which XLA also fuses well). ``interpret``
+    states the kernel mode; ``None`` derives it in one place
+    (:func:`repro.kernels.kernel_interpret`): native on a TPU, interpret
+    mode only where the CPU tests run."""
     n, d = points.shape
     k = centroids.shape[0]
     if not use_kernel:
@@ -32,11 +36,12 @@ def assign(points, centroids, *, use_kernel: bool = False, block_n: int = 1024, 
     kp = cp.shape[0]
     if kp > k:  # padded centroids must never win the argmin
         cp = cp.at[k:].set(1e30)
-    labels, dist = assign_pallas(pp, cp, block_n=min(block_n, pp.shape[0]), interpret=interpret)
+    labels, dist = assign_pallas(pp, cp, block_n=min(block_n, pp.shape[0]),
+                                 interpret=kernel_interpret(interpret))
     return labels[:n], dist[:n]
 
 
-def minibatch_update(points, centroids, *, decay: float = 0.9, use_kernel: bool = False, interpret: bool = True):
+def minibatch_update(points, centroids, *, decay: float = 0.9, use_kernel: bool = False, interpret: bool | None = None):
     """One streaming K-Means step: assign + decayed centroid update
     (paper §3.2.1 "averaging using a decay factor")."""
     k = centroids.shape[0]
@@ -52,7 +57,7 @@ def minibatch_update(points, centroids, *, decay: float = 0.9, use_kernel: bool 
 
 
 def minibatch_update_masked(points, centroids, n_valid, *, decay: float = 0.9,
-                            use_kernel: bool = False, interpret: bool = True):
+                            use_kernel: bool = False, interpret: bool | None = None):
     """Bucket-padded streaming step: rows ``>= n_valid`` are zero padding and
     contribute nothing to the update or the inertia.
 

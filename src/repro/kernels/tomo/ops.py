@@ -1,7 +1,9 @@
 """Jitted reconstruction ops: GridRec + ML-EM over either backend.
 
-``use_kernel=True`` runs the Pallas TPU projectors (``interpret=True`` on
-CPU); otherwise the jnp reference. GridRec's ramp filter always runs in XLA
+``use_kernel=True`` runs the Pallas TPU projectors, otherwise the jnp
+reference. ``interpret`` states the kernel mode; ``None`` derives it in one
+place (:func:`repro.kernels.kernel_interpret`): native on a TPU, interpret
+mode only where the CPU tests run. GridRec's ramp filter always runs in XLA
 (FFT is already optimal there).
 """
 from __future__ import annotations
@@ -11,6 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import kernel_interpret
 from repro.kernels.tomo import ref as R
 from repro.kernels.tomo.kernel import backproject_pallas, project_pallas
 
@@ -20,28 +23,30 @@ def _trig(angles):
     return jnp.cos(a), jnp.sin(a)
 
 
-def backproject(sino, angles, n, *, use_kernel=False, interpret=True):
+def backproject(sino, angles, n, *, use_kernel=False, interpret=None):
     if not use_kernel:
         return R.backproject_ref(sino, angles, n)
     cos_t, sin_t = _trig(angles)
-    return backproject_pallas(sino, cos_t, sin_t, n=n, interpret=interpret)
+    return backproject_pallas(sino, cos_t, sin_t, n=n,
+                              interpret=kernel_interpret(interpret))
 
 
-def project(img, angles, n_det, *, use_kernel=False, interpret=True):
+def project(img, angles, n_det, *, use_kernel=False, interpret=None):
     if not use_kernel:
         return R.project_ref(img, angles, n_det)
     cos_t, sin_t = _trig(angles)
-    return project_pallas(img, cos_t, sin_t, n_det=n_det, interpret=interpret)
+    return project_pallas(img, cos_t, sin_t, n_det=n_det,
+                          interpret=kernel_interpret(interpret))
 
 
-def gridrec(sino, angles, n, *, window="ramlak", use_kernel=False, interpret=True):
+def gridrec(sino, angles, n, *, window="ramlak", use_kernel=False, interpret=None):
     """FFT filtered backprojection (paper's fast reconstruction)."""
     filtered = R.ramp_filter(sino, window=window)
     bp = backproject(filtered, angles, n, use_kernel=use_kernel, interpret=interpret)
     return bp * (jnp.pi / (2.0 * angles.shape[0]))
 
 
-def mlem(sino, angles, n, *, iters=8, use_kernel=False, interpret=True):
+def mlem(sino, angles, n, *, iters=8, use_kernel=False, interpret=None):
     """Iterative ML-EM (paper's high-fidelity reconstruction)."""
     n_det = sino.shape[1]
     eps = 1e-6
@@ -74,7 +79,7 @@ def _project_batch(imgs, angles, n_det, *, use_kernel, interpret):
     return jax.vmap(fn, in_axes=(0, None))(imgs, angles)
 
 
-def gridrec_batch(sinos, angles, n, *, window="ramlak", use_kernel=False, interpret=True):
+def gridrec_batch(sinos, angles, n, *, window="ramlak", use_kernel=False, interpret=None):
     """Stacked GridRec over a (B, A, n_det) sinogram micro-batch — one fused
     call instead of a per-message Python loop (the streaming hot path)."""
     filtered = R.ramp_filter(sinos, window=window)  # filters along axis -1
@@ -82,7 +87,7 @@ def gridrec_batch(sinos, angles, n, *, window="ramlak", use_kernel=False, interp
     return bp * (jnp.pi / (2.0 * angles.shape[0]))
 
 
-def mlem_batch(sinos, angles, n, *, iters=8, use_kernel=False, interpret=True):
+def mlem_batch(sinos, angles, n, *, iters=8, use_kernel=False, interpret=None):
     """Stacked ML-EM over a (B, A, n_det) sinogram micro-batch."""
     b, _, n_det = sinos.shape
     eps = 1e-6

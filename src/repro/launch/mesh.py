@@ -6,8 +6,10 @@ this module never touches jax device state; the dry-run sets
 """
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -17,15 +19,11 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     return make_mesh(shape, axes)
 
 
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    # Auto axis semantics are the jax.make_mesh default; the pinned jax
-    # (0.4.37) predates the explicit jax.sharding.AxisType API.
-    return jax.make_mesh(shape, axes)
-
-
-def make_local_mesh(n_model: int = 1, n_data: int | None = None) -> Mesh:
-    """Mesh over whatever devices exist (tests / examples on CPU)."""
-    n = len(jax.devices())
-    if n_data is None:
-        n_data = n // n_model
-    return make_mesh((n_data, n_model), ("data", "model"))
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...],
+              devices: Sequence | None = None) -> Mesh:
+    """Mesh with Auto axes (jax.make_mesh defaults to Explicit ones, which
+    the sharding-constraint and shard_map code here does not use). With
+    ``devices``, the mesh spans exactly those devices instead of the first
+    ``prod(shape)`` of ``jax.devices()``."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)

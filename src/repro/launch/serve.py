@@ -21,6 +21,7 @@ from repro.elastic import MetricsBus
 from repro.launch import instrumented
 from repro.miniapps import LMServeApp, SourceConfig, TokenSource
 from repro.scheduler import ResourceRequest
+from repro.utils import enable_compile_cache
 
 
 def main() -> None:
@@ -32,6 +33,7 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen-tokens", type=int, default=8)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

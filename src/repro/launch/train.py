@@ -20,7 +20,9 @@ from repro.checkpoint import CheckpointManager
 from repro.core import PilotComputeService
 from repro.elastic import MetricsBus
 from repro.launch import instrumented
+from repro.launch.mesh import make_mesh
 from repro.miniapps import LMTrainApp, SourceConfig, TokenSource
+from repro.utils import enable_compile_cache
 from repro.runtime.optimizer import OptimizerConfig
 from repro.scheduler import ResourceRequest
 
@@ -39,6 +41,7 @@ def main() -> None:
     ap.add_argument("--checkpoint-every", type=int, default=10)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:
@@ -61,7 +64,10 @@ def main() -> None:
 
     opt = OptimizerConfig(name=cfg.optimizer, learning_rate=args.lr, warmup_steps=5,
                           total_steps=max(args.steps, 10))
-    app = LMTrainApp(cfg, opt_cfg=opt, seqs_per_step=args.batch, seq_len=args.seq_len)
+    # train on exactly the devices the pilot leased
+    mesh = make_mesh((held, 1), ("data", "model"), devices=spark.lease.devices)
+    app = LMTrainApp(cfg, mesh=mesh, opt_cfg=opt, seqs_per_step=args.batch,
+                     seq_len=args.seq_len)
     ckpt = CheckpointManager(args.checkpoint_dir, keep_last=2, async_save=True)
 
     state = None

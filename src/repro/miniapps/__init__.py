@@ -6,6 +6,7 @@ from repro.miniapps.mass import (
     LightsourceTemplateSource,
     RateStep,
     RateStepScenario,
+    ServingTraceSource,
     SourceConfig,
     StreamSource,
     TokenSource,
@@ -31,6 +32,7 @@ __all__ = [
     "RateStepScenario",
     "ReconstructionApp",
     "SOURCES",
+    "ServingTraceSource",
     "SourceConfig",
     "StreamSource",
     "StreamingKMeans",

@@ -16,8 +16,9 @@ small set of shape buckets so steady state never recompiles; per-message
 Python loops are replaced with stacked/vmapped per-micro-batch calls;
 results are double-buffered (``streaming.dispatch.AsyncWindow``) so batch
 N+1 dispatches while N executes, syncing only at stats/checkpoint/rescale
-boundaries; and ``use_kernel=True`` routes through the Pallas kernels
-(native on TPU, interpret-mode fallback elsewhere).
+boundaries; and ``use_kernel=True`` routes through the Pallas kernels,
+which compile natively on a TPU (the CPU tests run them in interpret
+mode; ``interpret=None`` derives that in ``repro.kernels``).
 """
 from __future__ import annotations
 
@@ -37,9 +38,17 @@ from repro.streaming.dispatch import (
     LatencyWindow,
     ShapeBuckets,
     compile_count,
-    kernel_interpret,
     pad_rows,
 )
+
+
+def _arch(cfg):
+    """An ArchConfig, or a registry name for one (keeps specs JSON-able)."""
+    if isinstance(cfg, str):
+        from repro.configs.registry import get_arch
+
+        return get_arch(cfg)
+    return cfg
 
 
 @dataclass
@@ -138,8 +147,6 @@ class StreamingKMeans(_HotPathApp):
         self.buckets = buckets or ShapeBuckets(min_size=512, max_size=65536)
         self._init_hotpath(async_depth=async_depth, metrics=metrics, name="kmeans")
         self._inertia = float("nan")
-        if interpret is None:
-            interpret = kernel_interpret()
         self._step = jax.jit(functools.partial(
             kmeans_ops.minibatch_update_masked,
             decay=decay, use_kernel=use_kernel, interpret=interpret,
@@ -181,6 +188,12 @@ class StreamingKMeans(_HotPathApp):
     def compiles(self) -> int:
         return compile_count(self._step if self.bucketed else self._step_legacy)
 
+    @property
+    def programs(self) -> dict:
+        """The jitted programs this processor dispatches, by name — for
+        callers that lower them to inspect what was compiled."""
+        return {"step": self._step, "step_legacy": self._step_legacy}
+
     def on_rescale(self, devices):
         # centroids are tiny: re-placement is a device_put
         def f(state):
@@ -209,8 +222,6 @@ class ReconstructionApp(_HotPathApp):
         self.batch_buckets = batch_buckets or ShapeBuckets(min_size=1, max_size=8)
         self._init_hotpath(async_depth=async_depth, metrics=metrics, name=algorithm)
         self._angles_cache: dict[int, jax.Array] = {}
-        if interpret is None:
-            interpret = kernel_interpret()
         if algorithm == "gridrec":
             one = functools.partial(tomo_ops.gridrec, n=n,
                                     use_kernel=use_kernel, interpret=interpret)
@@ -276,39 +287,55 @@ class ReconstructionApp(_HotPathApp):
 
     @property
     def compiles(self) -> int:
-        return compile_count(self._rec_batch if self.batched else self._rec)
+        """Compiles of every program the processor dispatches (the batched
+        mode runs single-frame groups through the scalar program too)."""
+        if not self.batched:
+            return compile_count(self._rec)
+        return compile_count(self._rec) + compile_count(self._rec_batch)
+
+    @property
+    def programs(self) -> dict:
+        """The jitted programs this processor dispatches, by name: ``frame``
+        takes (sinogram, angles), ``batch`` a stack of sinograms."""
+        return {"frame": self._rec, "batch": self._rec_batch}
 
 
 class LMTrainApp(_HotPathApp):
     """Streaming LM training: consume token messages, run train steps.
 
-    State = (params, opt_state); rescale re-lowers the step on a new mesh
-    and device_puts the live state (checkpoint-free migration). The train
+    State = (params, opt_state). Without an explicit ``mesh`` the stage
+    starts on one device (``jax.devices()[0]``, the first device a pilot
+    leases); rescale re-lowers the step on a data mesh over exactly the
+    devices it is handed and device_puts the live state (checkpoint-free
+    migration). The train
     step donates params/opt-state buffers, and per-step losses are read
     back lazily at sync boundaries instead of forcing a device round-trip
     per batch.
     """
 
     def __init__(self, cfg, *, mesh=None, opt_cfg=None, seqs_per_step: int = 8,
-                 seq_len: int = 128, async_depth: int = 2, metrics: Any = None):
-        from repro.launch.mesh import make_local_mesh
+                 seq_len: int = 128, async_depth: int = 2, metrics: Any = None,
+                 seed: int = 0):
+        from repro.launch.mesh import make_mesh
         from repro.models import build_model
         from repro.configs.base import ShapeConfig
         from repro.runtime.steps import build_train_step
 
-        self.cfg = cfg
+        self.cfg = cfg = _arch(cfg)
+        self.seed = seed
         self.model = build_model(cfg)
-        self.mesh = mesh or make_local_mesh()
+        self.mesh = mesh or make_mesh((1, 1), ("data", "model"),
+                                      devices=jax.devices()[:1])
         self.shape = ShapeConfig("stream", seq_len, seqs_per_step, "train")
         self.opt_cfg = opt_cfg
         self.bundle = build_train_step(self.model, self.mesh, self.shape, opt_cfg, donate=True)
         self._init_hotpath(async_depth=async_depth, metrics=metrics, name="lm_train")
         self._losses: list[float] = []
 
-    def init_state(self, seed: int = 0):
+    def init_state(self):
         from repro.runtime.optimizer import Optimizer, OptimizerConfig
 
-        params = self.model.init(jax.random.key(seed))
+        params = self.model.init(jax.random.key(self.seed))
         opt = Optimizer(self.opt_cfg or OptimizerConfig(name=self.cfg.optimizer))
         return {"params": params, "opt": opt.init(params)}
 
@@ -353,8 +380,8 @@ class LMTrainApp(_HotPathApp):
 
         def f(state):
             self.sync()  # in-flight steps must land before buffers move
-            n = len(devices)
-            self.mesh = make_mesh((n, 1), ("data", "model"))
+            self.mesh = make_mesh((len(devices), 1), ("data", "model"),
+                                  devices=list(devices))
             self.bundle = build_train_step(self.model, self.mesh, self.shape, self.opt_cfg, donate=True)
             if state is not None:
                 p_sh, o_sh, _ = self.bundle.in_shardings
@@ -387,11 +414,13 @@ class LMServeApp(_HotPathApp):
                  batch: int = 4, async_depth: int = 2, metrics: Any = None,
                  row_buckets: ShapeBuckets | None = None, mode: str = "lockstep",
                  n_pages: int = 256, page_size: int = 16,
-                 use_kernel: bool = False, interpret: bool | None = None):
+                 use_kernel: bool = False, interpret: bool | None = None,
+                 seed: int = 0):
         from repro.models import build_model
 
         assert mode in ("lockstep", "continuous"), mode
-        self.cfg = cfg
+        self.cfg = cfg = _arch(cfg)
+        self.seed = seed
         self.model = build_model(cfg)
         # single-host serving jits the model directly; a mesh is only needed
         # when the caller shards params explicitly, so none is built here
@@ -491,8 +520,14 @@ class LMServeApp(_HotPathApp):
         self._now = b.drain(self._now)
         return np.array([b.results[r]["tokens"] for r in rids], np.int32)
 
+    def init_state(self):
+        """Random weights from ``seed`` — the serving state when the stream
+        starts without params."""
+        return self.model.init(jax.random.key(self.seed))
+
     def process(self, state, msgs):
-        params = state  # serving state = model params
+        # serving state = model params
+        params = state if state is not None else self.init_state()
         t0 = time.monotonic()
         if self.mode == "continuous":
             out = self._serve_continuous(params, msgs)
@@ -518,6 +553,12 @@ class LMServeApp(_HotPathApp):
         if self.mode == "continuous":
             return self._batcher.decode_compiles
         return compile_count(self._generate)
+
+    @property
+    def batcher(self):
+        """The continuous mode's ContinuousBatcher (``results`` holds each
+        request's response by request id); None in lockstep mode."""
+        return self._batcher
 
     @property
     def prefill_compiles(self) -> int:

@@ -170,6 +170,11 @@ class LightsourceTemplateSource(StreamSource):
         reps = int(np.ceil(n_det / sino.shape[1]))
         self._payload = np.tile(sino, (1, reps))[:, :n_det].astype(np.float32)
 
+    @property
+    def frame(self) -> np.ndarray:
+        """The (n_angles, n_det) f32 sinogram every message carries."""
+        return self._payload
+
     def make_message(self, rng, i):
         return self._payload
 
@@ -188,6 +193,24 @@ class TokenSource(StreamSource):
         # zipfian-ish synthetic text: heavy head, long tail
         z = rng.zipf(1.3, size=(self.seqs_per_msg, self.seq_len))
         return np.minimum(z - 1, self.vocab_size - 1).astype(np.int32)
+
+
+class ServingTraceSource(StreamSource):
+    """LM serving requests: the prompts of a seeded heavy-tail trace
+    (``repro.serving.trace``), one request per message as a (1, prompt_len)
+    int32 row. The trace has ``total_messages`` requests drawn from
+    ``seed``; ``trace_options`` are further ``TraceConfig`` fields."""
+
+    def __init__(self, cluster, config, *, vocab_size: int, **trace_options):
+        super().__init__(cluster, config)
+        from repro.serving.trace import TraceConfig, heavy_tail_trace
+
+        self.trace = heavy_tail_trace(TraceConfig(
+            n_requests=config.total_messages or TraceConfig.n_requests,
+            seed=config.seed, vocab=vocab_size, **trace_options))
+
+    def make_message(self, rng, i):
+        return np.asarray(self.trace[i % len(self.trace)].prompt, np.int32)[None, :]
 
 
 @dataclass
@@ -256,4 +279,5 @@ SOURCES: dict[str, type[StreamSource]] = {
     "static": KMeansStaticSource,
     "lightsource": LightsourceTemplateSource,
     "tokens": TokenSource,
+    "serving_trace": ServingTraceSource,
 }

@@ -167,15 +167,15 @@ def decode_kernel_scope(*, block_kv: int = 128, interpret: bool | None = None):
 
     Trace-time routing: wrap the *tracing* call (the first invocation of a
     jitted decode step) — the traced HLO then contains the kernel for the
-    life of that compilation. ``interpret=None`` resolves to interpret mode
-    off-TPU (the correct-but-slow fallback), native on TPU.
+    life of that compilation. ``interpret=None`` derives the mode in one
+    place (:func:`repro.kernels.kernel_interpret`): native on a TPU,
+    interpret mode only where the CPU tests run.
     """
-    if interpret is None:
-        from repro.streaming.dispatch import kernel_interpret
+    from repro.kernels import kernel_interpret
 
-        interpret = kernel_interpret()
     prev = getattr(_DECODE_KERNEL, "cfg", None)
-    _DECODE_KERNEL.cfg = {"block_kv": int(block_kv), "interpret": bool(interpret)}
+    _DECODE_KERNEL.cfg = {"block_kv": int(block_kv),
+                          "interpret": kernel_interpret(interpret)}
     try:
         yield
     finally:

@@ -21,6 +21,7 @@ import time
 
 from repro.pipeline.builder import Pipeline
 from repro.pipeline.spec import PipelineSpec
+from repro.utils import enable_compile_cache
 
 
 def _load_spec(path: str) -> PipelineSpec:
@@ -57,6 +58,7 @@ def cmd_validate(args: argparse.Namespace) -> int:
 
 
 def cmd_run(args: argparse.Namespace) -> int:
+    enable_compile_cache()
     _import_modules(args.imports)
     spec = _load_spec(args.spec)
     errors = _validate(spec)

@@ -63,7 +63,8 @@ class SourceSpec:
 
     ``kind`` names a factory in the source registry — the built-in
     ``repro.miniapps.SOURCES`` kinds ("cluster", "static", "lightsource",
-    "tokens") plus anything registered via ``repro.pipeline.register_source``.
+    "tokens", "serving_trace") plus anything registered via
+    ``repro.pipeline.register_source``.
     """
 
     topic: str

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from repro.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -110,12 +110,8 @@ def vocab_parallel_cross_entropy(
             picked = jax.lax.psum(jnp.where(in_range, picked_loc, 0.0), "model")
             return ((lse - picked) * mi).sum()
 
-        # chunk count is static, so a Python loop works where lax.scan does
-        # not: the pre-promotion shard_map cannot transpose a scan inside the
-        # mapped body (its scalar carry residual breaks the spec check)
-        tot = jnp.float32(0.0)
-        for c in range(n_chunks):
-            tot = tot + step(xc[c], tc[c], mc[c])
+        tot, _ = jax.lax.scan(
+            lambda acc, c: (acc + step(*c), None), jnp.float32(0.0), (xc, tc, mc))
         # reduce over batch shards -> replicated scalar
         axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
         if axes:
@@ -129,7 +125,5 @@ def vocab_parallel_cross_entropy(
         out_specs=P(),
         check_vma=False,
     )
-    # the mask count needs no sharded compute, and keeping the mapped fn
-    # single-output sidesteps a pre-promotion shard_map transpose bug when
-    # several outputs carry nonzero cotangents (e.g. loss = tot / cnt)
+    # the mask count needs no sharded compute, so it stays outside the map
     return fn(x, head, targets, mask), mask.astype(jnp.float32).sum()

@@ -302,6 +302,12 @@ class ContinuousBatcher:
     def decode_compiles(self) -> int:
         return compile_count(self._decode)
 
+    @property
+    def programs(self) -> dict:
+        """The jitted step programs, by name — for callers that lower them
+        to inspect what was compiled (e.g. that decode holds its kernel)."""
+        return {"prefill": self._prefill, "decode": self._decode}
+
     def _publish_gauges(self) -> None:
         if self.metrics is None:
             return

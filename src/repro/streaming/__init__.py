@@ -3,7 +3,6 @@ from repro.streaming.dispatch import (
     LatencyWindow,
     ShapeBuckets,
     compile_count,
-    kernel_interpret,
     pad_rows,
 )
 from repro.streaming.rate_control import PIDRateController
@@ -24,6 +23,5 @@ __all__ = [
     "TumblingWindow",
     "WatermarkTracker",
     "compile_count",
-    "kernel_interpret",
     "pad_rows",
 ]

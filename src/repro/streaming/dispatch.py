@@ -79,12 +79,6 @@ def pad_rows(arr: np.ndarray, size: int) -> np.ndarray:
     return out
 
 
-def kernel_interpret() -> bool:
-    """Pallas kernels compile natively on TPU; everywhere else they run in
-    interpret mode (correct but slow — the automatic off-TPU fallback)."""
-    return jax.default_backend() != "tpu"
-
-
 def compile_count(jitted: Callable) -> int:
     """Number of distinct XLA compilations a jitted fn has performed."""
     try:

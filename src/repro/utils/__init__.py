@@ -1,4 +1,5 @@
-"""Small shared utilities: pytrees, timing, deterministic RNG streams."""
+"""Small shared utilities: pytrees, timing, the compilation cache."""
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.tree import (
     tree_bytes,
     tree_count,
@@ -9,6 +10,7 @@ from repro.utils.timer import Timer, now_monotonic
 
 __all__ = [
     "Timer",
+    "enable_compile_cache",
     "now_monotonic",
     "tree_bytes",
     "tree_count",

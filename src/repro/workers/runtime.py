@@ -53,6 +53,20 @@ from repro.workers.proto import (
 from repro.workers.supervisor import WorkerSupervisor
 
 
+def _refuse_fork_holding_tpu() -> None:
+    """A TPU belongs to one process: a forked child of a parent that has
+    initialized the TPU backend inherits handles to a chip it cannot use
+    and hangs or fails on it. Refuse before forking instead."""
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized() and "tpu" in xla_bridge.backends():
+        raise RuntimeError(
+            'executor="mp" cannot fork partition workers from a process '
+            "that holds a TPU backend (the chip belongs to one process); "
+            'run the stage with executor="inline", or start the mp stream '
+            "before anything in this process touches jax")
+
+
 class WorkerRuntime:
     def __init__(
         self,
@@ -110,6 +124,7 @@ class WorkerRuntime:
             raise RuntimeError(
                 'executor="mp" requires the fork start method (Linux): '
                 "window_fn/key_fn closures reach workers by inheritance")
+        _refuse_fork_holding_tpu()
         self._ctx = mp.get_context("fork")
         self.monitor = HeartbeatMonitor(self.heartbeat_interval,
                                         self.heartbeat_timeout)

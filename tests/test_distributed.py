@@ -16,7 +16,8 @@ from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.runtime.sharding import ShardingRules, activation_rules
 from repro.runtime.losses import vocab_parallel_cross_entropy, vocab_parallel_embed
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 rules = ShardingRules(mesh=mesh, batch_axes=("data",), kind="train")
 B, S, D, V = 4, 32, 16, 64
 ks = jax.random.split(jax.random.key(0), 3)
@@ -63,7 +64,8 @@ from repro.models.attention import naive_attention
 from repro.runtime.sharding import ShardingRules, activation_rules
 from repro.runtime.sharded_attention import sharded_attention
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 B, S, H, KV, hd = 4, 64, 6, 3, 16
 ks = jax.random.split(jax.random.key(0), 3)
 q = jax.random.normal(ks[0], (B, S, H, hd))
@@ -108,7 +110,8 @@ from repro.runtime.steps import build_train_step
 cfg = get_arch("qwen3-14b").reduced(d_model=64, d_ff=128, n_layers=2, vocab_size=256,
                                     n_heads=4, n_kv_heads=2, head_dim=16)
 model = build_model(cfg)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 shape = ShapeConfig("t", 64, 4, "train")
 bundle = build_train_step(model, mesh, shape, donate=False)
 params = model.init(jax.random.key(0))
@@ -138,8 +141,9 @@ import jax, jax.numpy as jnp, numpy as np, tempfile
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.checkpoint import CheckpointManager
 
-mesh8 = jax.make_mesh((8,), ("model",))
-mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh8 = make_mesh((8,), ("model",))
+mesh2 = make_mesh((2, 4), ("data", "model"))
 state = {"w": jax.device_put(jnp.arange(64.0).reshape(8, 8), NamedSharding(mesh8, P("model")))}
 d = tempfile.mkdtemp()
 mgr = CheckpointManager(d)
@@ -162,7 +166,8 @@ from repro.models.attention import naive_attention
 from repro.runtime.sharding import ShardingRules
 from repro.runtime.ring_attention import ring_attention_shmap
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 rules = ShardingRules(mesh=mesh, batch_axes=("data",), kind="prefill")
 B, S, H, KV, hd = 4, 64, 6, 3, 16
 ks = jax.random.split(jax.random.key(3), 3)

@@ -48,9 +48,10 @@ def test_compressed_pod_reduction_lowers_with_s8_collectives(subproc):
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.runtime.grad_compress import quantized_psum, resid_len
-from repro.utils.jax_compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2,), ("pod",))
+mesh = make_mesh((2,), ("pod",))
 
 def step(g, r):
     # per-pod partials enter with a leading pod dim; exchange inside shard_map
@@ -84,8 +85,9 @@ def test_compressed_dp_training_converges(subproc):
         """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2,), ("pod",))
+mesh = make_mesh((2,), ("pod",))
 key = jax.random.key(0)
 Xw = jax.random.normal(key, (64, 16))
 y = Xw @ jax.random.normal(jax.random.key(1), (16,))
@@ -94,7 +96,7 @@ def loss_fn(w, X, y):
     return jnp.mean((X @ w - y) ** 2)
 
 from repro.runtime.grad_compress import quantized_psum, resid_len
-from repro.utils.jax_compat import shard_map
+from jax import shard_map
 
 def make_step(compressed):
     def step(w, resid, X, y):

@@ -274,6 +274,19 @@ def test_unknown_executor_rejected(svc):
         _stream(svc, "bad", executor="threads")
 
 
+def test_mp_refuses_to_fork_from_a_process_holding_a_tpu(svc, monkeypatch):
+    """Forked workers of a parent that holds the chip would hang on it:
+    start() must refuse before any worker exists."""
+    from jax._src import xla_bridge
+
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized", lambda: True)
+    monkeypatch.setattr(xla_bridge, "backends", lambda: {"tpu": object()})
+    _, stream, _ = _stream(svc, "tpuheld", executor="mp")
+    with pytest.raises(RuntimeError, match="holds a TPU backend"):
+        stream.start()
+    assert stream.runtime is None or stream.runtime.n_workers == 0
+
+
 def test_mp_rescale_drains_stale_replies_before_quiesce(svc):
     """Satellite regression: a leftover BatchResult sitting in a worker's
     reply queue (an abandoned in-flight batch) must not alias the QUIESCE
