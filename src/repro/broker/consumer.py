@@ -26,6 +26,7 @@ from typing import Any, Callable
 from repro.broker.cluster import BrokerCluster
 from repro.broker.errors import BrokerUnavailable
 from repro.broker.records import Record, decode_array, decode_compressed, decode_msg
+from repro.elastic.metrics import span
 from repro.transport.frames import FrameBatch, decode_frame
 from repro.transport.plane import TAG_SLOT, FrameCache, decode_slot_record
 from repro.transport.ring import SlotReclaimedError, get_ring
@@ -212,6 +213,14 @@ class Consumer:
         return pos
 
     def poll(self, max_records: int = 512, timeout: float = 0.0) -> list[Message]:
+        """Up to ``max_records`` messages, waiting up to ``timeout`` seconds
+        for the first; an empty list when none came."""
+        with span("consumer.poll") as s:
+            out = self._poll(max_records, timeout)
+            s.set_metadata(records=len(out))
+        return out
+
+    def _poll(self, max_records: int, timeout: float) -> list[Message]:
         if self.injected_poll_delay > 0:
             time.sleep(self.injected_poll_delay)
         self._refresh_assignment()
@@ -301,6 +310,13 @@ class Consumer:
         processing keeps the at-least-once contract unchanged."""
         if zero_copy is None:
             zero_copy = self.zero_copy
+        with span("consumer.poll") as s:
+            out = self._poll_batch(max_records, timeout, zero_copy)
+            s.set_metadata(records=sum(len(b) for b in out))
+        return out
+
+    def _poll_batch(self, max_records: int, timeout: float,
+                    zero_copy: bool) -> list[PolledBatch]:
         if self.injected_poll_delay > 0:
             time.sleep(self.injected_poll_delay)
         self._refresh_assignment()

@@ -27,7 +27,6 @@ import time
 from dataclasses import dataclass
 from typing import Callable
 
-from repro.core.description import PilotComputeDescription
 from repro.elastic.events import EventLog, ScalingEvent
 from repro.elastic.metrics import MetricsBus, MetricsSnapshot
 from repro.elastic.policy import HOLD, ScalingDecision, ScalingPolicy
@@ -383,6 +382,10 @@ class ElasticController:
         return ScalingDecision(after - before, decision.reason)
 
     def _grow(self, n: int) -> None:
+        # imported here: repro.core loads the engines and the broker, which
+        # import this package's metrics module
+        from repro.core.description import PilotComputeDescription
+
         if self.unit == "nodes":
             # broker growth: the extension's *host slots* become cluster
             # nodes (BrokerPlugin.extend); no devices are consumed

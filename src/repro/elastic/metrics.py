@@ -42,6 +42,11 @@ Conventions (all optional — the bus is schemaless):
   per-stream readers resolve to the aggregate
 * ``elastic.rescale_deferred`` — the controller held a tick because the
   last state migration is still amortizing (``migration_cost_frac``)
+
+Spans (:func:`span`) time the same layers on the profiler's clock: while a
+``jax.profiler`` trace records, each is a host event beside the device's
+own, with stats such as the engine's batch id (docs/perf.md lists them);
+otherwise it costs one check.
 """
 from __future__ import annotations
 
@@ -50,6 +55,8 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterable
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclass(frozen=True)
@@ -171,6 +178,39 @@ class MetricsBus:
 
 
 # ---------------------------------------------------------------------------
+# spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+class _NoSpan:
+    """What :func:`span` returns while no profiler trace is recording."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set_metadata(self, **stats: Any) -> None:
+        pass
+
+
+NO_SPAN = _NoSpan()
+
+
+def span(name: str, **stats: Any):
+    """A named host span with ``stats``, recorded only while a profiler
+    trace is on (``jax.profiler.TraceAnnotation``, so it shares the device
+    events' clock); otherwise the shared no-op :data:`NO_SPAN`. Stats known
+    only at the end go in with ``set_metadata`` inside the ``with``."""
+    if not TraceAnnotation.is_enabled():
+        return NO_SPAN
+    return TraceAnnotation(name, **stats)
+
+
+# ---------------------------------------------------------------------------
 # per-engine stat records (moved here from engines/{microbatch,continuous}.py
 # so both engines and the control plane share one vocabulary)
 # ---------------------------------------------------------------------------
@@ -180,10 +220,9 @@ class MetricsBus:
 class BatchMetrics:
     batch_id: int
     n_records: int
-    bytes: int
+    bytes: int  # payload bytes the batch's polls consumed
     processing_delay: float
     scheduling_delay: float
-    end_to_end_latency: float  # now - oldest record timestamp
 
 
 @dataclass

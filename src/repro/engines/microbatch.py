@@ -23,7 +23,7 @@ from repro.core.compute_unit import ComputeUnit
 from repro.core.plugin import Lease, ManagerPlugin, register_plugin
 # stat records live on the shared elastic metrics bus now; re-exported here
 # for backward compatibility
-from repro.elastic.metrics import BatchMetrics, MetricsBus, StreamStats
+from repro.elastic.metrics import BatchMetrics, MetricsBus, StreamStats, span
 from repro.streaming.dispatch import LatencyWindow
 from repro.streaming.rate_control import PIDRateController
 
@@ -108,40 +108,43 @@ class MicroBatchStream:
         # average — the latency/throughput trade-off of paper Fig. 7)
         window_end = time.monotonic() + self.batch_interval
         msgs: list[Message] = []
-        while len(msgs) < limit:
-            remaining = window_end - time.monotonic()
-            if remaining <= 0:
-                break
-            got = self.consumer.poll(max_records=limit - len(msgs), timeout=remaining)
-            msgs.extend(got)
+        # spans of one batch carry the id it gets once processed
+        batch = self._batch_id + 1
+        bytes_before = self.consumer.consumed_bytes
+        with span("engine.collect", batch=batch) as collect:
+            while len(msgs) < limit:
+                remaining = window_end - time.monotonic()
+                if remaining <= 0:
+                    break
+                got = self.consumer.poll(max_records=limit - len(msgs), timeout=remaining)
+                msgs.extend(got)
+            collect.set_metadata(records=len(msgs))
         if not msgs:
             return 0
         scheduling_delay = max(time.monotonic() - window_end, 0.0)
         t0 = time.monotonic()
-        with self._state_lock:
+        with self._state_lock, span("engine.process", batch=batch, records=len(msgs)):
             self.state = self.process_fn(self.state, msgs)
         dt = time.monotonic() - t0
 
-        self._batch_id += 1
-        if self.checkpoint_fn and self._batch_id % self.checkpoint_every == 0:
-            if self.sync_fn is not None:  # land in-flight work before snapshotting
-                self.sync_fn()
-            self.checkpoint_fn(self.state, self.consumer.positions())
-        self.consumer.commit()  # after checkpoint -> exactly-once on replay
+        self._batch_id = batch
+        with span("engine.commit", batch=batch):
+            if self.checkpoint_fn and batch % self.checkpoint_every == 0:
+                if self.sync_fn is not None:  # land in-flight work before snapshotting
+                    self.sync_fn()
+                self.checkpoint_fn(self.state, self.consumer.positions())
+            self.consumer.commit()  # after checkpoint -> exactly-once on replay
 
         if self.controller is not None:
             self.controller.update(len(msgs), dt, scheduling_delay)
-        now = time.time()
+        consumed = self.consumer.consumed_bytes - bytes_before
         self.stats.batches += 1
         self.stats.records += len(msgs)
+        self.stats.bytes += consumed
         self.stats.processing_time += dt
         self.latency.record(dt)
         self.stats.history.append(
-            BatchMetrics(
-                self._batch_id, len(msgs), 0, dt, scheduling_delay,
-                now - min(m.timestamp for m in msgs),
-            )
-        )
+            BatchMetrics(batch, len(msgs), consumed, dt, scheduling_delay))
         if self.metrics is not None:
             self._publish_batch(len(msgs), dt, scheduling_delay)
         with self._batch_done:
@@ -197,7 +200,8 @@ class MicroBatchStream:
             if n == 0:
                 if self.metrics is not None:
                     self._publish_idle()
-                time.sleep(0.01)
+                with span("engine.idle"):
+                    time.sleep(0.01)
 
     # ---- control ------------------------------------------------------------
 

@@ -22,7 +22,6 @@ mode; ``interpret=None`` derives that in ``repro.kernels``).
 """
 from __future__ import annotations
 
-import functools
 import time
 from dataclasses import dataclass, field
 from typing import Any
@@ -31,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.elastic.metrics import span
 from repro.kernels import kmeans as kmeans_ops
 from repro.kernels import tomo as tomo_ops
 from repro.streaming.dispatch import (
@@ -147,27 +147,38 @@ class StreamingKMeans(_HotPathApp):
         self.buckets = buckets or ShapeBuckets(min_size=512, max_size=65536)
         self._init_hotpath(async_depth=async_depth, metrics=metrics, name="kmeans")
         self._inertia = float("nan")
-        self._step = jax.jit(functools.partial(
-            kmeans_ops.minibatch_update_masked,
-            decay=decay, use_kernel=use_kernel, interpret=interpret,
-        ))
-        self._step_legacy = jax.jit(functools.partial(
-            kmeans_ops.minibatch_update,
-            decay=decay, use_kernel=use_kernel, interpret=interpret,
-        ))
+
+        # named programs: the device trace shows them as jit_<name>
+        def kmeans_step(points, centroids, n_valid):
+            return kmeans_ops.minibatch_update_masked(
+                points, centroids, n_valid,
+                decay=decay, use_kernel=use_kernel, interpret=interpret)
+
+        def kmeans_step_unbucketed(points, centroids):
+            return kmeans_ops.minibatch_update(
+                points, centroids, decay=decay, use_kernel=use_kernel, interpret=interpret)
+
+        self._step = jax.jit(kmeans_step)
+        self._step_legacy = jax.jit(kmeans_step_unbucketed)
 
     def process(self, state, msgs):
         centroids = state if state is not None else self.centroids
-        pts = np.concatenate([np.asarray(m.value) for m in msgs]).astype(np.float32)
-        n = pts.shape[0]
-        t0 = time.monotonic()
+        with span("app.prep") as prep:
+            pts = np.concatenate([np.asarray(m.value) for m in msgs]).astype(np.float32)
+            n = pts.shape[0]
+            prep.set_metadata(items=n)
+            t0 = time.monotonic()
+            if self.bucketed:
+                pts = pad_rows(pts, self.buckets.fit(n))
+            points = jnp.asarray(pts)
         if self.bucketed:
-            padded = pad_rows(pts, self.buckets.fit(n))
             # n is a dynamic scalar: every size sharing a bucket reuses the
             # same executable
-            centroids, labels, inertia = self._step(jnp.asarray(padded), centroids, n)
+            with span("app.dispatch", program="kmeans_step"):
+                centroids, labels, inertia = self._step(points, centroids, n)
         else:
-            centroids, labels, inertia = self._step_legacy(jnp.asarray(pts), centroids)
+            with span("app.dispatch", program="kmeans_step_unbucketed"):
+                centroids, labels, inertia = self._step_legacy(points, centroids)
         self.stats.messages += len(msgs)
         self.stats.items += n
         self.stats.batches += 1
@@ -222,16 +233,27 @@ class ReconstructionApp(_HotPathApp):
         self.batch_buckets = batch_buckets or ShapeBuckets(min_size=1, max_size=8)
         self._init_hotpath(async_depth=async_depth, metrics=metrics, name=algorithm)
         self._angles_cache: dict[int, jax.Array] = {}
-        if algorithm == "gridrec":
-            one = functools.partial(tomo_ops.gridrec, n=n,
-                                    use_kernel=use_kernel, interpret=interpret)
-            many = functools.partial(tomo_ops.gridrec_batch, n=n,
-                                     use_kernel=use_kernel, interpret=interpret)
-        else:
-            one = functools.partial(tomo_ops.mlem, n=n, iters=mlem_iters,
-                                    use_kernel=use_kernel, interpret=interpret)
-            many = functools.partial(tomo_ops.mlem_batch, n=n, iters=mlem_iters,
-                                     use_kernel=use_kernel, interpret=interpret)
+
+        # named programs: the device trace shows them as jit_<name>
+        def gridrec_frame(sino, angles):
+            return tomo_ops.gridrec(sino, angles, n, use_kernel=use_kernel,
+                                    interpret=interpret)
+
+        def gridrec_stack(sinos, angles):
+            return tomo_ops.gridrec_batch(sinos, angles, n, use_kernel=use_kernel,
+                                          interpret=interpret)
+
+        def mlem_frame(sino, angles):
+            return tomo_ops.mlem(sino, angles, n, iters=mlem_iters,
+                                 use_kernel=use_kernel, interpret=interpret)
+
+        def mlem_stack(sinos, angles):
+            return tomo_ops.mlem_batch(sinos, angles, n, iters=mlem_iters,
+                                       use_kernel=use_kernel, interpret=interpret)
+
+        one, many = ((gridrec_frame, gridrec_stack) if algorithm == "gridrec"
+                     else (mlem_frame, mlem_stack))
+        self._program_names = (one.__name__, many.__name__)
         self._rec = jax.jit(one)
         self._rec_batch = jax.jit(many)
 
@@ -257,20 +279,31 @@ class ReconstructionApp(_HotPathApp):
         return recon  # last reconstruction = state (exposed for inspection)
 
     def _process_batched(self, msgs):
-        groups: dict[tuple, list[np.ndarray]] = {}
-        for m in msgs:
-            sino = np.asarray(m.value, np.float32)
-            groups.setdefault(sino.shape, []).append(sino)
-        last_shape = np.asarray(msgs[-1].value).shape
+        with span("app.prep", frames=len(msgs)):
+            groups: dict[tuple, list[np.ndarray]] = {}
+            for m in msgs:
+                sino = np.asarray(m.value, np.float32)
+                groups.setdefault(sino.shape, []).append(sino)
+            last_shape = np.asarray(msgs[-1].value).shape
+            # (shape, frames, device input, angles) per shape group
+            inputs = []
+            for shape, frames in groups.items():
+                if len(frames) == 1:
+                    x = frames[0]
+                else:
+                    x = pad_rows(np.stack(frames), self.batch_buckets.fit(len(frames)))
+                inputs.append((shape, len(frames), jnp.asarray(x), self._angles(shape[0])))
+        frame_program, stack_program = self._program_names
         recon = None
-        for shape, frames in groups.items():
-            angles = self._angles(shape[0])
-            if len(frames) == 1:
+        for shape, n_frames, x, angles in inputs:
+            if n_frames == 1:
                 # the scalar path beats a B=1 batched matmul (degenerate gemm)
-                rec = self._rec(jnp.asarray(frames[0]), angles)
+                with span("app.dispatch", program=frame_program):
+                    rec = self._rec(x, angles)
             else:
-                stack = pad_rows(np.stack(frames), self.batch_buckets.fit(len(frames)))
-                rec = self._rec_batch(jnp.asarray(stack), angles)[len(frames) - 1]
+                with span("app.dispatch", program=stack_program):
+                    recs = self._rec_batch(x, angles)
+                rec = recs[n_frames - 1]
             # state contract: the LAST message's reconstruction (its frame is
             # the last element of its shape group)
             if shape == last_shape:
@@ -280,9 +313,11 @@ class ReconstructionApp(_HotPathApp):
     def _process_loop(self, msgs):
         recon = None
         for m in msgs:
-            sino = jnp.asarray(np.asarray(m.value), jnp.float32)
-            angles = jnp.linspace(0, jnp.pi, sino.shape[0], endpoint=False)
-            recon = self._rec(sino, angles)
+            with span("app.prep", frames=1):
+                sino = jnp.asarray(np.asarray(m.value), jnp.float32)
+                angles = jnp.linspace(0, jnp.pi, sino.shape[0], endpoint=False)
+            with span("app.dispatch", program=self._program_names[0]):
+                recon = self._rec(sino, angles)
         return recon
 
     @property

@@ -31,6 +31,8 @@ from typing import Any, Callable
 import jax
 import numpy as np
 
+from repro.elastic.metrics import span
+
 
 def next_pow2(n: int) -> int:
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
@@ -147,8 +149,9 @@ class AsyncWindow:
         return done
 
     def _wait_oldest(self) -> tuple[Any, Any, float]:
-        result, meta, t0 = self._pending.popleft()
-        jax.block_until_ready(result)
+        with span("app.wait"):
+            result, meta, t0 = self._pending.popleft()
+            jax.block_until_ready(result)
         dt = time.monotonic() - t0
         if self.latency is not None:
             self.latency.record(dt)

@@ -15,8 +15,14 @@ class TumblingWindow:
     size: float
 
     def assign(self, ts: float) -> list[Window]:
-        start = math.floor(ts / self.size) * self.size
-        return [(start, start + self.size)]
+        # window k is [k * size, (k + 1) * size): both edges are the same
+        # products for every timestamp, and ts / size may round across one
+        k = math.floor(ts / self.size)
+        while k * self.size > ts:
+            k -= 1
+        while (k + 1) * self.size <= ts:
+            k += 1
+        return [(k * self.size, (k + 1) * self.size)]
 
 
 @dataclass(frozen=True)
