@@ -65,6 +65,17 @@ def test_tomo_backproject_compiles_at_paper_size(one_chip, no_persistent_cache):
     assert "tpu_custom_call" in text
 
 
+def test_tomo_backproject_stack_compiles_at_paper_size(one_chip, no_persistent_cache):
+    """The reconstruction stage's stack program vmaps the kernel over frames."""
+    from repro.kernels.tomo.kernel import backproject_pallas
+
+    f32 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    stack = lambda s, c, si: jax.vmap(lambda x: backproject_pallas(x, c, si, n=N))(s)
+    text = _compiled_text(
+        stack, f32((2, N_ANGLES, N_DET)), f32((N_ANGLES,)), f32((N_ANGLES,)))
+    assert "tpu_custom_call" in text
+
+
 def test_tomo_project_compiles_at_paper_size(one_chip, no_persistent_cache):
     from repro.kernels.tomo.kernel import project_pallas
 

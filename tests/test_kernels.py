@@ -80,6 +80,50 @@ def test_tomo_projectors_match_ref(n, n_det, a):
     )
 
 
+_QUARTERS = [0.0, np.pi / 4, np.pi / 2, 3 * np.pi / 4]
+
+
+@pytest.mark.parametrize(
+    "n,n_det,angles,batch",
+    [
+        (40, 40, np.linspace(0, np.pi, 10, endpoint=False), None),
+        (72, 64, np.linspace(0, np.pi, 12, endpoint=False), None),
+        # pixels beyond both detector ends: their taps are dropped
+        (96, 48, np.linspace(0, np.pi, 9, endpoint=False), None),
+        (48, 56, _QUARTERS, None),
+        (40, 48, np.linspace(0, np.pi, 7, endpoint=False), 3),
+    ],
+    ids=["n40", "n72", "n_past_detector", "quarter_angles", "stack3"],
+)
+def test_tomo_backproject_kernel_matches_ref(n, n_det, angles, batch):
+    angles = jnp.asarray(angles, jnp.float32)
+    shape = (angles.shape[0], n_det) if batch is None else (batch, angles.shape[0], n_det)
+    sino = jax.random.normal(jax.random.key(n + n_det), shape, jnp.float32)
+    if batch is None:
+        got = backproject(sino, angles, n, use_kernel=True, interpret=True)
+        want = backproject_ref(sino, angles, n)
+    else:
+        got = jax.vmap(lambda s: backproject(s, angles, n, use_kernel=True, interpret=True))(sino)
+        want = jnp.stack([backproject_ref(s, angles, n) for s in sino])
+    assert got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-4)
+
+
+def test_tomo_backproject_kernel_is_the_adjoint_of_project_ref():
+    """ML-EM pairs the kernel's backprojection with the forward projector:
+    <P x, y> = <x, B y>. Positive inputs and float64 dot products, so the
+    only rounding is the projectors' own."""
+    n, n_det, a = 40, 48, 12
+    angles = jnp.linspace(0, jnp.pi, a, endpoint=False)
+    x = jax.random.uniform(jax.random.key(2), (n, n))
+    y = jax.random.uniform(jax.random.key(3), (a, n_det))
+    px = np.asarray(project_ref(x, angles, n_det), np.float64)
+    by = np.asarray(backproject(y, angles, n, use_kernel=True, interpret=True), np.float64)
+    lhs = np.vdot(px, np.asarray(y, np.float64))
+    rhs = np.vdot(np.asarray(x, np.float64), by)
+    np.testing.assert_allclose(rhs, lhs, rtol=1e-5)
+
+
 def test_tomo_projectors_are_adjoint():
     n, n_det, a = 24, 32, 12
     angles = jnp.linspace(0, jnp.pi, a, endpoint=False)
