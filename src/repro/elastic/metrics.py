@@ -231,6 +231,9 @@ class StreamStats:
     records: int = 0
     bytes: int = 0
     processing_time: float = 0.0
+    #: batches whose window closed before the batch interval because a
+    #: record-wise processor had drained the device
+    idle_closes: int = 0
     history: list = field(default_factory=list)
 
     @property

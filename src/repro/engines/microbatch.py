@@ -5,6 +5,8 @@ the broker, assembles a batch, and applies a (usually jitted) processing
 function carrying state (model params, centroids, ...). Provides:
 
 * PID backpressure (streaming/rate_control.py) bounding per-batch ingestion;
+* a work-conserving window for record-wise processors: the window closes
+  once it holds records and the processor has nothing left on the device;
 * exactly-once: state checkpoint then offset commit, atomically ordered —
   recovery restores the checkpoint and rewinds to committed offsets;
 * elastic rescale: extension pilots add devices; the processor's
@@ -26,6 +28,11 @@ from repro.core.plugin import Lease, ManagerPlugin, register_plugin
 from repro.elastic.metrics import BatchMetrics, MetricsBus, StreamStats, span
 from repro.streaming.dispatch import LatencyWindow
 from repro.streaming.rate_control import PIDRateController
+
+#: longest poll while a record-wise window holds records and the device is
+#: still busy: the consumer's own wait step, so the window closes within one
+#: step of the device draining
+DRAIN_POLL_S = 0.002
 
 
 class MicroBatchStream:
@@ -79,6 +86,8 @@ class MicroBatchStream:
         self.stats = StreamStats()
         self.latency = LatencyWindow()
         self._processor = owner
+        #: see ``_HotPathApp.record_wise`` (miniapps/masa.py)
+        self._record_wise = getattr(owner, "record_wise", False) is True
         self.metrics = metrics
         #: bus label for this stream's gauges. Defaults to the topic; two
         #: stages consuming one topic need distinct labels (the declarative
@@ -105,7 +114,14 @@ class MicroBatchStream:
             limit = min(limit, self.controller.max_records_per_batch)
         # discretized-stream semantics: the window accumulates for the full
         # batch interval before processing fires (records wait ~window/2 on
-        # average — the latency/throughput trade-off of paper Fig. 7)
+        # average — the latency/throughput trade-off of paper Fig. 7). A
+        # record-wise processor's window also closes as soon as it holds
+        # records and the processor has no batch unfinished on the device:
+        # the trade-off pays only while the device is busy, so the interval
+        # bounds a record's hold instead of fixing it. A checkpointing stream
+        # keeps the clock: each extra batch would cost a sync and a snapshot.
+        early = self._record_wise and self.checkpoint_fn is None
+        closed = "interval"
         window_end = time.monotonic() + self.batch_interval
         msgs: list[Message] = []
         # spans of one batch carry the id it gets once processed
@@ -116,9 +132,16 @@ class MicroBatchStream:
                 remaining = window_end - time.monotonic()
                 if remaining <= 0:
                     break
+                if early and msgs:
+                    if not getattr(self._processor, "busy", False):
+                        closed = "idle"
+                        break
+                    remaining = min(remaining, DRAIN_POLL_S)
                 got = self.consumer.poll(max_records=limit - len(msgs), timeout=remaining)
                 msgs.extend(got)
-            collect.set_metadata(records=len(msgs))
+            if len(msgs) >= limit:
+                closed = "full"
+            collect.set_metadata(records=len(msgs), closed=closed)
         if not msgs:
             return 0
         scheduling_delay = max(time.monotonic() - window_end, 0.0)
@@ -139,6 +162,8 @@ class MicroBatchStream:
             self.controller.update(len(msgs), dt, scheduling_delay)
         consumed = self.consumer.consumed_bytes - bytes_before
         self.stats.batches += 1
+        if closed == "idle":
+            self.stats.idle_closes += 1
         self.stats.records += len(msgs)
         self.stats.bytes += consumed
         self.stats.processing_time += dt

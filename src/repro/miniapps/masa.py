@@ -74,6 +74,13 @@ class _HotPathApp:
     completed results call it implicitly.
     """
 
+    #: whether each record's output depends on that record alone, not on how
+    #: records are grouped into batches: the micro-batch engine then closes a
+    #: window once it holds records and ``busy`` is false. A fact of the
+    #: algorithm, not an option; processors that fold a batch into state keep
+    #: the engine's clock window
+    record_wise = False
+
     def _init_hotpath(self, *, async_depth: int = 2, metrics: Any = None,
                       name: str | None = None) -> None:
         self.stats = AppStats()
@@ -122,6 +129,12 @@ class _HotPathApp:
     @property
     def in_flight(self) -> int:
         return self._window.in_flight
+
+    @property
+    def busy(self) -> bool:
+        """Whether a dispatched batch is unfinished on the device (does not
+        block; see ``AsyncWindow.busy``)."""
+        return self._window.busy
 
 
 class StreamingKMeans(_HotPathApp):
@@ -220,6 +233,9 @@ class ReconstructionApp(_HotPathApp):
     depth to a small bucket set so compile count stays bounded.
     ``batched=False, async_depth=0`` is the legacy per-message loop.
     """
+
+    #: each frame's reconstruction depends only on that frame
+    record_wise = True
 
     def __init__(self, algorithm: str = "gridrec", *, n: int = 64, mlem_iters: int = 4,
                  use_kernel: bool = False, batched: bool = True,

@@ -177,3 +177,14 @@ class AsyncWindow:
     @property
     def in_flight(self) -> int:
         return len(self._pending)
+
+    @property
+    def busy(self) -> bool:
+        """Whether a pending result is still computing on the device. Does
+        not block. Unlike ``in_flight``, an entry whose result is ready but
+        not yet drained (the window drains only past ``depth`` or at
+        ``sync()``) does not count."""
+        return any(not leaf.is_ready()
+                   for result, _, _ in tuple(self._pending)
+                   for leaf in jax.tree_util.tree_leaves(result)
+                   if isinstance(leaf, jax.Array))

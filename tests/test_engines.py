@@ -1,14 +1,21 @@
-"""Engines: micro-batch exactly-once, PID backpressure, continuous windows,
-taskpool speculative execution, pilot lifecycle + failure recovery."""
+"""Engines: micro-batch exactly-once, PID backpressure, the record-wise
+window close, continuous windows, taskpool speculative execution, pilot
+lifecycle + failure recovery."""
+import sys
 import threading
 import time
+from pathlib import Path
 
 import numpy as np
 import pytest
 
 from repro.broker import BrokerCluster, Producer
 from repro.core import CUState, PilotComputeDescription, PilotComputeService
+from repro.engines.microbatch import MicroBatchStream
+from repro.miniapps import LMServeApp, LMTrainApp, ReconstructionApp, StreamingKMeans
 from repro.streaming import PIDRateController, TumblingWindow
+
+ROOT = Path(__file__).resolve().parents[1]
 
 
 @pytest.fixture
@@ -76,6 +83,169 @@ def test_pid_controller_reduces_rate_under_overload():
     r2 = pid.update(n_records=1000, processing_delay=0.4)  # 4x overloaded
     assert r2 < r1
     assert pid.max_records_per_batch < 1000
+
+
+# ---------------------------------------------------------------------------
+# the window of a record-wise processor closes once its device has drained
+# ---------------------------------------------------------------------------
+
+
+class _RecordWise:
+    """A record-wise processor whose device the test drives through ``busy``."""
+
+    record_wise = True
+
+    def __init__(self, busy=False):
+        self.busy = busy
+        self.batches: list[list[float]] = []
+        self.started: list[float] = []
+
+    def process(self, state, msgs):
+        self.started.append(time.monotonic())
+        self.batches.append([float(np.asarray(m.value).flat[0]) for m in msgs])
+        return state
+
+
+def _microbatch(process_fn, interval, **kw):
+    cluster = BrokerCluster(1)
+    cluster.create_topic("t", 1)
+    stream = MicroBatchStream(cluster, "t", group="g", process_fn=process_fn,
+                              batch_interval=interval, backpressure=False, **kw)
+    return cluster, stream, Producer(cluster, "t", serializer="npy")
+
+
+def test_record_wise_window_closes_once_the_device_is_idle():
+    app = _RecordWise()
+    cluster, stream, prod = _microbatch(app.process, 2.0)
+    stream.start()
+    try:
+        time.sleep(0.05)
+        sent = time.monotonic()
+        prod.send(np.array([1.0]))
+        stream.await_batches(1, timeout=1.5)  # the clock would hold it to 2 s
+    finally:
+        stream.stop()
+        cluster.close()
+    assert app.batches == [[1.0]]
+    assert app.started[0] - sent < 0.5
+    assert stream.stats.idle_closes == 1
+
+
+@pytest.mark.parametrize("drains", [True, False], ids=["drained", "interval"])
+def test_records_held_while_busy_come_out_as_one_batch(drains):
+    """While the device is busy the window gathers records as the clock
+    window does; it closes once the device drains, or at the interval."""
+    app = _RecordWise(busy=True)
+    interval = 2.0 if drains else 0.4
+    cluster, stream, prod = _microbatch(app.process, interval)
+    stream.start()
+    start = time.monotonic()
+    try:
+        for i in range(3):
+            prod.send(np.array([float(i)]))
+            time.sleep(0.01)
+        time.sleep(0.05)
+        assert app.batches == []
+        app.busy = not drains
+        stream.await_batches(1, timeout=5)
+    finally:
+        stream.stop()
+        cluster.close()
+    assert app.batches == [[0.0, 1.0, 2.0]]
+    if drains:
+        assert app.started[0] - start < 1.0
+        assert stream.stats.idle_closes == 1
+    else:
+        assert app.started[0] - start >= interval - 0.05
+        assert stream.stats.idle_closes == 0
+
+
+def test_max_batch_records_still_caps_a_record_wise_window():
+    app = _RecordWise()
+    cluster, stream, prod = _microbatch(app.process, 2.0, max_batch_records=2)
+    prod.send_batch([np.array([float(i)]) for i in range(5)])
+    stream.start()
+    try:
+        stream.await_batches(3, timeout=1.5)
+    finally:
+        stream.stop()
+        cluster.close()
+    assert app.batches == [[0.0, 1.0], [2.0, 3.0], [4.0]]
+    assert stream.stats.idle_closes == 1  # the first two closed full
+
+
+def _kmeans():
+    app = StreamingKMeans(n_clusters=2, dim=1)
+    return app.process, {}
+
+
+def _plain_callable():
+    return (lambda state, msgs: state), {}
+
+
+def _checkpointing():
+    return _RecordWise().process, {"checkpoint_fn": lambda state, offsets: None}
+
+
+@pytest.mark.parametrize("make", [_kmeans, _plain_callable, _checkpointing],
+                         ids=lambda f: f.__name__.lstrip("_"))
+def test_other_streams_keep_the_clock_window(make):
+    """Records 10 ms apart inside one window land in one batch: KMeans folds
+    a batch into its centroids, a plain callable declares nothing, and a
+    checkpointing stream would snapshot every extra batch."""
+    process_fn, kw = make()
+    cluster, stream, prod = _microbatch(process_fn, 0.3, **kw)
+    stream.start()
+    try:
+        for i in range(3):
+            prod.send(np.array([[float(i)]]))
+            time.sleep(0.01)
+        stream.await_batches(1, timeout=30)
+    finally:
+        stream.stop()
+        cluster.close()
+    assert [b.n_records for b in stream.stats.history] == [3]
+    assert stream.stats.idle_closes == 0
+
+
+def test_only_reconstruction_declares_record_wise():
+    assert ReconstructionApp.record_wise is True
+    for cls in (StreamingKMeans, LMTrainApp, LMServeApp):
+        assert cls.record_wise is False, cls
+
+
+class _Collector:
+    def on_ready(self, batch, result, outputs):
+        pass
+
+
+def test_record_wise_window_closes_through_the_benchmark_wrapper():
+    """The benchmark wraps the processor in ``TimedProcessor``, which
+    forwards ``record_wise`` and ``busy`` to the app inside."""
+    if str(ROOT) not in sys.path:
+        sys.path.insert(0, str(ROOT))
+    from benchmarks.chip.harness import Recorder, TimedProcessor
+
+    app = ReconstructionApp("gridrec", n=16)
+    recorder = Recorder(_Collector())
+    timed = TimedProcessor(app, recorder)
+    assert timed.record_wise is True and timed.busy is False
+    sent = time.time()
+    recorder.arm(np.array([sent]))
+    cluster, stream, prod = _microbatch(timed.process, 2.0, deserialize=True)
+    assert stream.sync_fn == timed.sync
+    stream.start()
+    try:
+        t0 = time.monotonic()
+        prod.send(np.ones((8, 16), np.float32), timestamp=sent)
+        stream.await_batches(1, timeout=30)
+    finally:
+        stream.stop()
+        cluster.close()
+        recorder.close()
+    assert recorder.batches[0].start - t0 < 0.5
+    assert stream.stats.idle_closes == 1
+    assert timed.busy is False and app.in_flight == 0
 
 
 def test_continuous_event_time_windows(svc):

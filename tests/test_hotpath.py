@@ -66,6 +66,23 @@ def test_async_window_bounds_in_flight_and_syncs():
     assert len(w.latency) == 5 and w.latency.p99 >= w.latency.p50 >= 0.0
 
 
+def test_async_window_busy_reads_readiness_not_in_flight():
+    """``busy`` is true while a pushed result computes and false once it is
+    ready, though ``in_flight`` counts the entry until it is drained."""
+    w = AsyncWindow(depth=2)
+    assert not w.busy
+    step = jax.jit(lambda x: jax.lax.fori_loop(0, 40, lambda i, a: jnp.tanh(a @ a), x))
+    x = jnp.full((1024, 1024), 1e-3, jnp.float32)
+    step(x).block_until_ready()  # compile outside the check
+    result = step(x)
+    w.push(result)
+    assert w.busy and w.in_flight == 1
+    jax.block_until_ready(result)
+    assert not w.busy and w.in_flight == 1
+    w.sync()
+    assert not w.busy and w.in_flight == 0
+
+
 def test_async_window_depth_zero_is_synchronous():
     w = AsyncWindow(depth=0)
     done = w.push(jnp.ones((2,)), meta="a")

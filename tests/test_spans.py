@@ -27,7 +27,7 @@ PROGRAM_SPANS = ("consumer.poll", "engine.collect", "engine.process", "engine.co
 def _pipeline():
     spec = (Pipeline.named("spans")
             .broker(nodes=1, transport="shm",
-                    transport_options={"slot_bytes": ANGLES * DET * 4 + 4096, "n_slots": 8})
+                    transport_options={"slot_bytes": 3 * ANGLES * DET * 4 + 4096, "n_slots": 8})
             .topic("frames", partitions=1)
             .stage("s", topic="frames", processor="gridrec", transport="shm",
                    batch_interval=0.02, max_batch_records=4, n=32, use_kernel=True)
@@ -41,13 +41,15 @@ def _frames(n):
 
 
 def _feed(run, frames, bursts):
-    """Send ``bursts`` (frames each, back to back), then wait for them."""
+    """Send ``bursts`` (frames each, one ``send_batch`` a burst, so that a
+    poll returns the burst whole and the engine, whose window closes as soon
+    as the idle processor holds a record, batches it whole), then wait for
+    them."""
     stream = run.stream("s")
     producer = Producer(run.cluster, "frames")
     want = stream.stats.records + sum(bursts)
     for size in bursts:
-        for f in frames[:size]:
-            producer.send_batch([f])
+        producer.send_batch(frames[:size])
         time.sleep(0.06)
     deadline = time.monotonic() + 60
     while stream.stats.records < want and time.monotonic() < deadline:
@@ -102,6 +104,15 @@ def test_every_span_is_traced(traced):
     for name in ("engine.collect", "engine.process", "engine.commit"):
         assert all(isinstance(s[3].get("batch"), int) for s in traced[name]), name
     assert {s[3]["program"] for s in traced["app.dispatch"]} == {"gridrec_frame", "gridrec_stack"}
+
+
+def test_collect_says_why_it_closed(traced):
+    closed = [s[3]["closed"] for s in traced["engine.collect"]]
+    assert set(closed) <= {"idle", "interval", "full"}
+    # gridrec is record-wise: a burst into the drained engine closes early
+    assert "idle" in closed
+    assert all(s[3]["closed"] == "interval" for s in traced["engine.collect"]
+               if s[3]["records"] == 0)
 
 
 def test_spans_nest_as_the_layers(traced):
