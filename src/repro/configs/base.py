@@ -5,7 +5,8 @@ dataclass drives:
 
 * parameter-spec construction (``models.build_model``),
 * sharding rules (``runtime.sharding``),
-* the dry-run (``launch.dryrun``) via ``input_specs()``,
+* the input structures the train/serve steps lower against
+  (``input_specs()``),
 * reduced smoke-test configs (``cfg.reduced()``).
 """
 from __future__ import annotations
@@ -16,7 +17,7 @@ from dataclasses import dataclass, field
 from typing import Any
 
 # ---------------------------------------------------------------------------
-# Input shapes assigned to the LM family (seq_len, global_batch, kind).
+# An input shape for the LM steps (seq_len, global_batch, kind).
 # ---------------------------------------------------------------------------
 
 
@@ -26,14 +27,6 @@ class ShapeConfig:
     seq_len: int
     global_batch: int
     kind: str  # "train" | "prefill" | "decode"
-
-
-SHAPES: dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
-}
 
 
 # ---------------------------------------------------------------------------

@@ -3,7 +3,7 @@
 [arXiv:2501.kimi2; unverified]
 61L d_model=7168 64H (GQA kv=8) d_ff=2048 vocab=163840, MoE 384e top-8,
 1 shared expert (DeepSeek-V3-style). Trains only with full ZeRO-3 over all
-chips + bf16/factored optimizer state — see EXPERIMENTS.md §Dry-run.
+chips + bf16/factored optimizer state.
 """
 from repro.configs.base import ArchConfig
 

@@ -1,7 +1,7 @@
 """Architecture registry: ``get_arch("qwen3-14b") -> ArchConfig``."""
 from __future__ import annotations
 
-from repro.configs.base import SHAPES, ArchConfig, ShapeConfig
+from repro.configs.base import ArchConfig
 from repro.configs.llava_next_mistral_7b import CONFIG as LLAVA
 from repro.configs.seamless_m4t_medium import CONFIG as SEAMLESS
 from repro.configs.phi35_moe import CONFIG as PHI35
@@ -29,30 +29,9 @@ ARCHS: dict[str, ArchConfig] = {
     ]
 }
 
-#: archs whose sequence mixing is sub-quadratic -> eligible for long_500k
-SUBQUADRATIC = {"rwkv6-3b", "zamba2-1.2b"}
-
-
 def get_arch(name: str) -> ArchConfig:
     try:
         return ARCHS[name]
     except KeyError:
         raise KeyError(f"unknown arch {name!r}; known: {sorted(ARCHS)}") from None
 
-
-def get_shape(name: str) -> ShapeConfig:
-    try:
-        return SHAPES[name]
-    except KeyError:
-        raise KeyError(f"unknown shape {name!r}; known: {sorted(SHAPES)}") from None
-
-
-def cell_supported(arch: str, shape: str) -> tuple[bool, str]:
-    """Whether (arch, shape) is a runnable dry-run cell; else (False, why)."""
-    if shape == "long_500k" and arch not in SUBQUADRATIC:
-        return False, "full-attention arch: 512k dense KV is quadratic-regime (see DESIGN.md §Arch-applicability)"
-    return True, ""
-
-
-def all_cells() -> list[tuple[str, str]]:
-    return [(a, s) for a in ARCHS for s in SHAPES]

@@ -3,7 +3,7 @@
 [arXiv:2404.05892; hf]
 32L d_model=2560 (attn-free) d_ff=8960 vocab=65536. WKV6 recurrence with
 data-dependent per-channel decay; chunked-parallel implementation.
-Sub-quadratic -> runs long_500k.
+Sub-quadratic in sequence length.
 """
 from repro.configs.base import ArchConfig
 

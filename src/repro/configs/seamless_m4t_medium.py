@@ -3,8 +3,8 @@
 [arXiv:2308.11596; hf]
 12L d_model=1024 16H (kv=16, MHA) d_ff=4096 vocab=256206; enc-dec.
 The speech frontend is a STUB: ``input_specs()`` provides precomputed frame
-embeddings (B, S_enc, d_model) for the encoder. ``train_4k`` splits the
-sequence budget 1/2 encoder frames + 1/2 decoder tokens (DESIGN.md §5).
+embeddings (B, S_enc, d_model) for the encoder. A training shape
+splits its sequence budget 1/2 encoder frames + 1/2 decoder tokens.
 """
 from repro.configs.base import ArchConfig
 

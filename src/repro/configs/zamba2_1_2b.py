@@ -4,8 +4,8 @@
 38L d_model=2048 32H (kv=32) d_ff=8192 vocab=32000, ssm_state=64.
 The shared attention+MLP block is applied every ``shared_block_every``
 Mamba2 layers (weight-tied across applications, Zamba2-style).
-Sub-quadratic backbone -> runs long_500k (attention sites are decode-time
-KV reads, O(seq) per token).
+Sub-quadratic backbone (attention sites are decode-time KV reads, O(seq)
+per token).
 """
 from repro.configs.base import ArchConfig
 

@@ -1,6 +1,6 @@
 """PilotComputeService: device pool, leases, pilot lifecycle, failure injection.
 
-The TPU-native rendering of the paper's Pilot-Job machinery (DESIGN.md §2):
+The TPU-native rendering of the paper's Pilot-Job machinery:
 a *pilot* is a lease over a slice of the device pool plus a framework plugin
 provisioned on it. ``submit_pilot`` is the paper's Listing 2;
 ``parent=`` in the description is the extension mechanism of Listing 4.
@@ -34,7 +34,7 @@ class DevicePool:
     """Tracks free/leased devices and logical host slots.
 
     Host slots (for the broker) are unbounded-logical; devices are the real
-    ``jax.devices()`` (or an explicit list for dry-runs/tests).
+    ``jax.devices()`` (or an explicit list for tests).
     """
 
     def __init__(self, devices: list | None = None, n_host_slots: int = 1 << 16):

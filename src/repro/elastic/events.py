@@ -2,7 +2,7 @@
 
 ``timeline`` folds the MetricsBus history plus the controller's event log
 into one JSON-serializable dict — lag / devices / throughput vs. time —
-consumed by ``benchmarks/elasticity.py`` and ``docs/elastic.md`` plots.
+for the plots described in ``docs/elastic.md``.
 """
 from __future__ import annotations
 

@@ -10,8 +10,8 @@ function carrying state (model params, centroids, ...). Provides:
 * exactly-once: state checkpoint then offset commit, atomically ordered —
   recovery restores the checkpoint and rewinds to committed offsets;
 * elastic rescale: extension pilots add devices; the processor's
-  ``on_rescale`` hook re-shards live state (DESIGN.md §2 "resharding, not
-  node hand-off").
+  ``on_rescale`` hook re-shards live state (resharding, not node
+  hand-off; docs/elastic.md).
 """
 from __future__ import annotations
 

@@ -1,6 +1,4 @@
-"""Launchers: mesh construction, multi-pod dry-run, train/serve drivers,
-roofline analysis. ``dryrun`` must be run as a fresh process (it forces 512
-host devices before jax initializes)."""
+"""Launchers: mesh construction and the train/serve drivers."""
 import time
 
 

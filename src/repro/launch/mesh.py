@@ -1,22 +1,11 @@
-"""Production mesh builders.
-
-``make_production_mesh`` is a FUNCTION (not module-level state) so importing
-this module never touches jax device state; the dry-run sets
-``xla_force_host_platform_device_count`` before calling it.
-"""
+"""Mesh builder. A function, not module-level state, so importing this
+module never touches jax device state."""
 from __future__ import annotations
 
 from typing import Sequence
 
 import jax
 from jax.sharding import AxisType, Mesh
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    """Single pod: 16x16 ("data","model"). Multi-pod: 2x16x16 ("pod","data","model")."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...],

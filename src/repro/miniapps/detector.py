@@ -6,9 +6,8 @@ generated — or loaded from an HDF5 dataset — up front, then replayed at a
 controlled rate, so the measured ceiling is the *transport*, not the
 generator. Frames go out through :meth:`Producer.send_batch` in columnar
 batches: on an shm-mounted topic each batch is one ring-slot write plus
-slot-handle records (see docs/transport.md), which is what lets
-``benchmarks/transport.py`` chase msgs/s numbers the per-message serde
-path can't reach.
+slot-handle records (see docs/transport.md), which spares the stream the
+per-message serde path.
 
 HDF5 input is optional and gated on ``h5py`` being importable; without
 it (or without a path) frames are synthetic Poisson-ish counts in the

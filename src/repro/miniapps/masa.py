@@ -12,13 +12,14 @@ Each exposes ``process(state, msgs) -> state`` for
 elastic path (live state resharding).
 
 Hot-path design (docs/perf.md): variable-length batches are padded to a
-small set of shape buckets so steady state never recompiles; per-message
-Python loops are replaced with stacked/vmapped per-micro-batch calls;
-results are double-buffered (``streaming.dispatch.AsyncWindow``) so batch
-N+1 dispatches while N executes, syncing only at stats/checkpoint/rescale
-boundaries; and ``use_kernel=True`` routes through the Pallas kernels,
-which compile natively on a TPU (the CPU tests run them in interpret
-mode; ``interpret=None`` derives that in ``repro.kernels``).
+small set of shape buckets so steady state never recompiles; frames of
+one shape are stacked into one vmapped call per micro-batch; results are
+double-buffered (``streaming.dispatch.AsyncWindow``, ``ASYNC_DEPTH``) so
+batch N+1 dispatches while N executes, syncing only at
+stats/checkpoint/rescale boundaries; and ``use_kernel=True`` routes
+through the Pallas kernels, which compile natively on a TPU (the CPU
+tests run them in interpret mode; ``interpret=None`` derives that in
+``repro.kernels``).
 """
 from __future__ import annotations
 
@@ -40,6 +41,10 @@ from repro.streaming.dispatch import (
     compile_count,
     pad_rows,
 )
+
+#: batches a processor keeps in flight before it blocks on the oldest one
+#: (double buffering: batch N+1 dispatches while N executes)
+ASYNC_DEPTH = 2
 
 
 def _arch(cfg):
@@ -81,19 +86,17 @@ class _HotPathApp:
     #: the engine's clock window
     record_wise = False
 
-    def _init_hotpath(self, *, async_depth: int = 2, metrics: Any = None,
-                      name: str | None = None) -> None:
+    def _init_hotpath(self, *, metrics: Any = None, name: str | None = None) -> None:
         self.stats = AppStats()
         self.metrics = metrics
         self._metrics_name = name or type(self).__name__
-        self._window = AsyncWindow(async_depth, self.stats.latency)
+        self._window = AsyncWindow(ASYNC_DEPTH, self.stats.latency)
 
     def _submit(self, result: Any, meta: Any = None, t0: float | None = None) -> None:
         """Enqueue a dispatched batch; ``t0`` = start of the batch's host
         work, so drained latencies span prep+compute. ``compute_time`` sums
-        those per-batch completion latencies — identical to the legacy
-        block-every-batch accounting at depth 0, and the honest per-batch
-        cost (not mere dispatch time) when batches overlap."""
+        those per-batch completion latencies: the per-batch cost, not mere
+        dispatch time, when batches overlap."""
         for res, m, dt in self._window.push(result, meta, t0=t0):
             self.stats.compute_time += dt
             self._on_complete(res, m, dt)
@@ -140,25 +143,21 @@ class _HotPathApp:
 class StreamingKMeans(_HotPathApp):
     """Assign incoming points to centroids, update the model with decay.
 
-    ``bucketed=True`` pads each batch up to a power-of-two row bucket and
-    runs the masked update — bit-identical centroids, at most
-    ``len(buckets)`` compiles regardless of how batch sizes vary.
-    ``bucketed=False, async_depth=0`` reproduces the legacy one-compile-per-
-    shape, block-every-batch behavior (the benchmark baseline).
+    Each batch is padded up to a power-of-two row bucket and runs the masked
+    update: the centroids are bit-identical to the unpadded update, and
+    there are at most ``len(buckets)`` compiles however batch sizes vary.
     """
 
     def __init__(self, n_clusters: int = 10, dim: int = 3, *, decay: float = 0.9,
                  use_kernel: bool = False, seed: int = 0,
-                 bucketed: bool = True, buckets: ShapeBuckets | None = None,
-                 async_depth: int = 2, interpret: bool | None = None,
-                 metrics: Any = None):
+                 buckets: ShapeBuckets | None = None,
+                 interpret: bool | None = None, metrics: Any = None):
         rng = np.random.default_rng(seed)
         self.centroids = jnp.asarray(rng.normal(size=(n_clusters, dim)), jnp.float32)
         self.decay = decay
         self.use_kernel = use_kernel
-        self.bucketed = bucketed
         self.buckets = buckets or ShapeBuckets(min_size=512, max_size=65536)
-        self._init_hotpath(async_depth=async_depth, metrics=metrics, name="kmeans")
+        self._init_hotpath(metrics=metrics, name="kmeans")
         self._inertia = float("nan")
 
         # named programs: the device trace shows them as jit_<name>
@@ -167,12 +166,7 @@ class StreamingKMeans(_HotPathApp):
                 points, centroids, n_valid,
                 decay=decay, use_kernel=use_kernel, interpret=interpret)
 
-        def kmeans_step_unbucketed(points, centroids):
-            return kmeans_ops.minibatch_update(
-                points, centroids, decay=decay, use_kernel=use_kernel, interpret=interpret)
-
         self._step = jax.jit(kmeans_step)
-        self._step_legacy = jax.jit(kmeans_step_unbucketed)
 
     def process(self, state, msgs):
         centroids = state if state is not None else self.centroids
@@ -181,17 +175,11 @@ class StreamingKMeans(_HotPathApp):
             n = pts.shape[0]
             prep.set_metadata(items=n)
             t0 = time.monotonic()
-            if self.bucketed:
-                pts = pad_rows(pts, self.buckets.fit(n))
-            points = jnp.asarray(pts)
-        if self.bucketed:
-            # n is a dynamic scalar: every size sharing a bucket reuses the
-            # same executable
-            with span("app.dispatch", program="kmeans_step"):
-                centroids, labels, inertia = self._step(points, centroids, n)
-        else:
-            with span("app.dispatch", program="kmeans_step_unbucketed"):
-                centroids, labels, inertia = self._step_legacy(points, centroids)
+            points = jnp.asarray(pad_rows(pts, self.buckets.fit(n)))
+        # n is a dynamic scalar: every size sharing a bucket reuses the same
+        # executable
+        with span("app.dispatch", program="kmeans_step"):
+            centroids, labels, inertia = self._step(points, centroids, n)
         self.stats.messages += len(msgs)
         self.stats.items += n
         self.stats.batches += 1
@@ -210,13 +198,13 @@ class StreamingKMeans(_HotPathApp):
 
     @property
     def compiles(self) -> int:
-        return compile_count(self._step if self.bucketed else self._step_legacy)
+        return compile_count(self._step)
 
     @property
     def programs(self) -> dict:
         """The jitted programs this processor dispatches, by name — for
         callers that lower them to inspect what was compiled."""
-        return {"step": self._step, "step_legacy": self._step_legacy}
+        return {"step": self._step}
 
     def on_rescale(self, devices):
         # centroids are tiny: re-placement is a device_put
@@ -228,26 +216,23 @@ class StreamingKMeans(_HotPathApp):
 class ReconstructionApp(_HotPathApp):
     """Per-frame tomographic reconstruction (GridRec or ML-EM).
 
-    ``batched=True`` groups a micro-batch's frames by sinogram shape, stacks
-    each group and reconstructs it in one vmapped call, padding the stack
-    depth to a small bucket set so compile count stays bounded.
-    ``batched=False, async_depth=0`` is the legacy per-message loop.
+    A micro-batch's frames are grouped by sinogram shape; each group of more
+    than one frame is stacked and reconstructed in one vmapped call, its
+    depth padded to a small bucket set so the compile count stays bounded.
     """
 
     #: each frame's reconstruction depends only on that frame
     record_wise = True
 
     def __init__(self, algorithm: str = "gridrec", *, n: int = 64, mlem_iters: int = 4,
-                 use_kernel: bool = False, batched: bool = True,
-                 batch_buckets: ShapeBuckets | None = None, async_depth: int = 2,
+                 use_kernel: bool = False, batch_buckets: ShapeBuckets | None = None,
                  interpret: bool | None = None, metrics: Any = None):
         assert algorithm in ("gridrec", "mlem")
         self.algorithm = algorithm
         self.n = n
         self.use_kernel = use_kernel
-        self.batched = batched
         self.batch_buckets = batch_buckets or ShapeBuckets(min_size=1, max_size=8)
-        self._init_hotpath(async_depth=async_depth, metrics=metrics, name=algorithm)
+        self._init_hotpath(metrics=metrics, name=algorithm)
         self._angles_cache: dict[int, jax.Array] = {}
 
         # named programs: the device trace shows them as jit_<name>
@@ -284,17 +269,6 @@ class ReconstructionApp(_HotPathApp):
 
     def process(self, state, msgs):
         t0 = time.monotonic()
-        if self.batched:
-            recon = self._process_batched(msgs)
-        else:
-            recon = self._process_loop(msgs)
-        self.stats.messages += len(msgs)
-        self.stats.items += len(msgs)
-        self.stats.batches += 1
-        self._submit(recon, t0=t0)
-        return recon  # last reconstruction = state (exposed for inspection)
-
-    def _process_batched(self, msgs):
         with span("app.prep", frames=len(msgs)):
             groups: dict[tuple, list[np.ndarray]] = {}
             for m in msgs:
@@ -324,24 +298,16 @@ class ReconstructionApp(_HotPathApp):
             # the last element of its shape group)
             if shape == last_shape:
                 recon = rec
-        return recon
-
-    def _process_loop(self, msgs):
-        recon = None
-        for m in msgs:
-            with span("app.prep", frames=1):
-                sino = jnp.asarray(np.asarray(m.value), jnp.float32)
-                angles = jnp.linspace(0, jnp.pi, sino.shape[0], endpoint=False)
-            with span("app.dispatch", program=self._program_names[0]):
-                recon = self._rec(sino, angles)
-        return recon
+        self.stats.messages += len(msgs)
+        self.stats.items += len(msgs)
+        self.stats.batches += 1
+        self._submit(recon, t0=t0)
+        return recon  # last reconstruction = state (exposed for inspection)
 
     @property
     def compiles(self) -> int:
-        """Compiles of every program the processor dispatches (the batched
-        mode runs single-frame groups through the scalar program too)."""
-        if not self.batched:
-            return compile_count(self._rec)
+        """Compiles of every program the processor dispatches (single-frame
+        groups run through the scalar program, larger ones the stack)."""
         return compile_count(self._rec) + compile_count(self._rec_batch)
 
     @property
@@ -365,8 +331,7 @@ class LMTrainApp(_HotPathApp):
     """
 
     def __init__(self, cfg, *, mesh=None, opt_cfg=None, seqs_per_step: int = 8,
-                 seq_len: int = 128, async_depth: int = 2, metrics: Any = None,
-                 seed: int = 0):
+                 seq_len: int = 128, metrics: Any = None, seed: int = 0):
         from repro.launch.mesh import make_mesh
         from repro.models import build_model
         from repro.configs.base import ShapeConfig
@@ -380,7 +345,7 @@ class LMTrainApp(_HotPathApp):
         self.shape = ShapeConfig("stream", seq_len, seqs_per_step, "train")
         self.opt_cfg = opt_cfg
         self.bundle = build_train_step(self.model, self.mesh, self.shape, opt_cfg, donate=True)
-        self._init_hotpath(async_depth=async_depth, metrics=metrics, name="lm_train")
+        self._init_hotpath(metrics=metrics, name="lm_train")
         self._losses: list[float] = []
 
     def init_state(self):
@@ -462,7 +427,7 @@ class LMServeApp(_HotPathApp):
     """
 
     def __init__(self, cfg, *, mesh=None, prompt_len: int = 32, gen_tokens: int = 8,
-                 batch: int = 4, async_depth: int = 2, metrics: Any = None,
+                 batch: int = 4, metrics: Any = None,
                  row_buckets: ShapeBuckets | None = None, mode: str = "lockstep",
                  n_pages: int = 256, page_size: int = 16,
                  use_kernel: bool = False, interpret: bool | None = None,
@@ -481,7 +446,7 @@ class LMServeApp(_HotPathApp):
         self.batch = batch
         self.mode = mode
         self.row_buckets = row_buckets or ShapeBuckets(min_size=batch, max_size=batch * 8)
-        self._init_hotpath(async_depth=async_depth, metrics=metrics, name="lm_serve")
+        self._init_hotpath(metrics=metrics, name="lm_serve")
         # cache sized for prompt + generation inside the jitted path: growing
         # it afterwards (jnp.pad on the host) copied the entire KV cache per
         # batch (see _prefill_grown)

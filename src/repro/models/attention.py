@@ -1,6 +1,6 @@
 """Attention: GQA with blockwise (flash) / naive / ring backends + KV cache.
 
-Distribution scheme (DESIGN.md §4): heads are never sharded — Q/K/V
+Distribution scheme: heads are never sharded — Q/K/V
 activations are *sequence*-sharded on the "model" mesh axis, which removes
 every head-count divisibility constraint of the assigned pool (9/24/40 heads,
 kv=2/3/8 on a 16-way axis). Blockwise attention keeps the O(block) memory

@@ -15,7 +15,8 @@ class BaseModel:
     """A model = param specs + pure functions (loss / prefill / decode).
 
     Subclasses implement ``param_specs``, ``loss``, ``prefill``,
-    ``decode`` and the shape-struct providers used by the dry-run.
+    ``decode`` and the shape-struct providers the step builders lower
+    against.
     """
 
     #: model family supports the paged-KV serving path (runtime/steps.py
@@ -60,7 +61,7 @@ class BaseModel:
         """One decode step; returns (logits, updated cache)."""
         raise NotImplementedError
 
-    # ---- dry-run structs ---------------------------------------------------
+    # ---- input structs -----------------------------------------------------
 
     def input_specs(self, shape: ShapeConfig) -> dict:
         """ShapeDtypeStruct stand-ins for every model input of this shape."""

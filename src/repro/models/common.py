@@ -4,7 +4,7 @@ Parameters are plain nested dicts of arrays. Every leaf is declared through a
 :class:`ParamSpec` carrying *logical axis names*; ``runtime.sharding`` maps
 those names onto mesh axes. The same spec tree serves real initialization
 (smoke tests, examples) and allocation-free ``ShapeDtypeStruct`` trees
-(dry-run).
+(lowering a step without data).
 """
 from __future__ import annotations
 

@@ -175,7 +175,7 @@ class EncDecLM(BaseModel):
         logits = x.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
         return logits, {"k": k, "v": v, "k_mem": cache["k_mem"], "v_mem": cache["v_mem"]}
 
-    # ---- dry-run structs -------------------------------------------------------
+    # ---- input structs ---------------------------------------------------------
 
     def input_specs(self, shape: ShapeConfig) -> dict:
         cfg = self.cfg

@@ -173,7 +173,7 @@ def mamba_apply(cfg, lp: dict, x: jax.Array, state: dict | None, *, compute_dtyp
     Bg = Bm[:, :, None, :].astype(jnp.float32)  # (B,T,1,N)
     Cg = Cm[:, :, None, :].astype(jnp.float32)
     S0 = jnp.zeros((B_, H, P, ds), jnp.float32) if state is None else state["ssd"]
-    # sequence-parallel core when activations are seq-sharded (DESIGN.md §4)
+    # sequence-parallel core when activations are seq-sharded
     from repro.runtime.sharding import _CTX
 
     rules = getattr(_CTX, "rules", None)

@@ -7,7 +7,7 @@ Two numerically-equivalent WKV6 implementations:
   exponents are differences of within-chunk cumulative log-decays and hence
   <= 0 (no overflow); the intra-chunk score needs a per-channel decay factor
   so it is a 3-operand einsum (VPU work; the channel-mix matmuls dominate
-  FLOPs by ~300x, see DESIGN.md).
+  FLOPs by ~300x).
 
 State per layer/head: S in R^{N x N} (key-dim x value-dim):
     o_t = r_t^T (S_{t-1} + u . k_t v_t^T)
@@ -281,7 +281,7 @@ class Rwkv6LM(BaseModel):
         logits = x.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
         return logits, states
 
-    # ---- dry-run structs ------------------------------------------------------
+    # ---- input structs --------------------------------------------------------
 
     def input_specs(self, shape: ShapeConfig) -> dict:
         B, S = shape.global_batch, shape.seq_len

@@ -273,7 +273,7 @@ class DecoderLM(BaseModel):
         logits = x.astype(jnp.float32) @ self._head(params).T.astype(jnp.float32)
         return logits, {"k": k, "v": v}
 
-    # ---- dry-run structs -------------------------------------------------
+    # ---- input structs ---------------------------------------------------
 
     def input_specs(self, shape: ShapeConfig) -> dict:
         B, S = shape.global_batch, shape.seq_len

@@ -4,8 +4,8 @@ The shared transformer block (attention + MLP, one set of weights) is applied
 before every ``shared_block_every``-th Mamba2 layer, consuming
 ``concat([x, x0])`` (current stream + original embeddings) as in Zamba2 —
 the concat restores information the SSM stream may have compressed away.
-Per-invocation LoRA adapters from the released model are omitted
-(DESIGN.md §5); everything else follows the published layout.
+Per-invocation LoRA adapters from the released model are omitted;
+everything else follows the published layout.
 """
 from __future__ import annotations
 
@@ -167,7 +167,7 @@ class ZambaLM(BaseModel):
         }
         return logits, new_cache
 
-    # ---- dry-run structs -------------------------------------------------
+    # ---- input structs ---------------------------------------------------
 
     def input_specs(self, shape: ShapeConfig) -> dict:
         B, S = shape.global_batch, shape.seq_len

@@ -1,7 +1,7 @@
 """Static analysis of compiled HLO text: flops, HBM traffic, collective bytes.
 
 Why this exists: XLA's ``compiled.cost_analysis()`` counts while-loop bodies
-exactly once (measured — see EXPERIMENTS.md §Dry-run), so scanned-layer
+exactly once, so scanned-layer
 models under-report flops by ~n_layers. This module parses
 ``compiled.as_text()`` and walks the call graph, multiplying loop bodies by
 their parsed trip counts.

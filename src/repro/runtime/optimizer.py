@@ -3,7 +3,7 @@
 Built from scratch (no optax in the environment). Optimizer state mirrors the
 parameter tree so it inherits parameter shardings (fully-sharded states =
 ZeRO); Adafactor's factored second moment drops the dominant state term for
-the trillion-parameter config (DESIGN.md §5).
+the trillion-parameter config.
 """
 from __future__ import annotations
 

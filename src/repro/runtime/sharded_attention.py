@@ -3,7 +3,7 @@
 Why not plain GSPMD: a flash-style q-block loop is a *sequential* construct;
 under GSPMD with the sequence dim sharded, reshaping (S,) -> (nq, bq) forces
 an all-gather and the loop serializes across shards (measured: ~390 GB/device
-collectives on smollm train_4k). The SPMD-correct structure maps the q-block
+collectives on smollm training at 4k tokens). The SPMD-correct structure maps the q-block
 loop onto the mesh: each "model" shard owns S/16 query rows and runs a local
 online-softmax loop over KV blocks.
 
@@ -104,7 +104,7 @@ def local_flash(q, k, v, *, q_offset, causal: bool, block_kv: int, scale: float,
 # custom-VJP flash: no per-block residuals saved (bwd recomputes each block),
 # causal early-exit in both directions. This is what bounds train-time
 # attention memory to O(block) and halves causal attention flops vs the
-# masked static scan (EXPERIMENTS.md §Perf).
+# masked static scan.
 # ---------------------------------------------------------------------------
 
 

@@ -1,15 +1,15 @@
 """Logical-axis -> PartitionSpec rules (divisibility-checked).
 
-Params and activations carry *logical* axis names (DESIGN.md §4); this module
+Params and activations carry *logical* axis names; this module
 maps them onto the mesh:
 
 * tensor-parallel names ("vocab", "mlp", "qkv", "heads", "kv", "experts")
   shard on the "model" axis;
 * "batch" shards on ("pod","data") (greedily trimmed so the dim divides);
 * "seq" (train/prefill activations) shards on "model" (sequence parallelism —
-  no head-count divisibility constraints, DESIGN.md §4);
+  no head-count divisibility constraints);
 * "cache_seq" (decode KV caches) shards on "model", and additionally takes
-  the "data" axis when the batch is too small to use it (long_500k, B=1);
+  the "data" axis when the batch is too small to use it (long-context decode, B=1);
 * ZeRO: every parameter additionally shards its largest unmapped dim over
   ("pod","data") when divisible (optimizer state inherits param shardings).
 

@@ -1,9 +1,9 @@
 """Step builders: jit-wired train / prefill / decode steps for a (model, mesh).
 
 ``build_*`` return a :class:`StepBundle` holding the jitted function plus the
-in/out shardings and ShapeDtypeStruct trees needed both by the dry-run
-(``.lower(...)`` on structs) and by live execution (device_put real arrays to
-the same shardings).
+in/out shardings and ShapeDtypeStruct trees needed both to lower the step
+without data (``.lower()`` on structs) and by live execution (device_put
+real arrays to the same shardings).
 """
 from __future__ import annotations
 

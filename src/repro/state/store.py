@@ -258,7 +258,7 @@ def serialize_partition(part: StatePartition) -> bytes:
     shape) signature stack into one contiguous blob, so restore pays one
     ``frombuffer`` per group instead of one numpy call per message —
     per-message envelopes dominated migration latency at large state
-    sizes (benchmarks/rescale_state.py).
+    sizes.
     """
     groups: dict[tuple, list] = {}  # (dtype.str, shape) -> [gid, [arrays]]
     # flat per-message columns (msgpack C-packs homogeneous lists fast and

@@ -126,21 +126,22 @@ def test_update_scatter_matches_matmul_oracle():
     np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
 
 
-def test_bucketed_kmeans_app_bit_identical_to_legacy():
+def test_bucketed_kmeans_app_bit_identical_to_unpadded_reference():
     """The whole app path: padded bucketed dispatch + async double-buffering
-    must reproduce the legacy block-every-batch centroids exactly."""
+    must reproduce, exactly, the plain update over each unpadded batch."""
     rng = np.random.default_rng(1)
     batches = [[Msg(rng.normal(size=(int(rng.integers(100, 1500)), 3)))] for _ in range(10)]
-    new = StreamingKMeans(n_clusters=6, dim=3, seed=2)
-    old = StreamingKMeans(n_clusters=6, dim=3, seed=2, bucketed=False, async_depth=0)
-    s_new = s_old = None
+    app = StreamingKMeans(n_clusters=6, dim=3, seed=2)
+    ref_step = jax.jit(lambda p, c: minibatch_update(p, c, decay=app.decay))
+    state, ref = None, app.centroids
     for b in batches:
-        s_new = new.process(s_new, b)
-        s_old = old.process(s_old, b)
-    new.sync()
-    np.testing.assert_array_equal(np.asarray(s_new), np.asarray(s_old))
-    assert new.inertia == pytest.approx(old.inertia)
-    assert new.stats.messages == old.stats.messages == 10
+        state = app.process(state, b)
+        pts = np.asarray(b[0].value, np.float32)
+        ref, _, ref_inertia = ref_step(jnp.asarray(pts), ref)
+    app.sync()
+    np.testing.assert_array_equal(np.asarray(state), np.asarray(ref))
+    assert app.inertia == pytest.approx(float(ref_inertia) / len(pts))
+    assert app.stats.messages == 10
 
 
 def test_kmeans_recompile_count_bounded_by_buckets():
@@ -155,7 +156,7 @@ def test_kmeans_recompile_count_bounded_by_buckets():
     app.sync()
     assert app.compiles <= len(buckets)
     assert app.compiles == len({buckets.fit(int(n)) for n in sizes})
-    # legacy comparison: one compile per distinct size
+    # unbucketed, it would compile once per distinct size
     assert len(set(int(n) for n in sizes)) > len(buckets)
 
 
@@ -210,6 +211,13 @@ def test_batched_reconstruction_matches_sequential(fn_batch, fn_one, kw):
             rtol=1e-5, atol=1e-5)
 
 
+def _gridrec_reference(msg, n):
+    """The plain reference: jitted gridrec of one frame on its own."""
+    sino = jnp.asarray(np.asarray(msg.value), jnp.float32)
+    angles = jnp.linspace(0, jnp.pi, sino.shape[0], endpoint=False)
+    return jax.jit(gridrec, static_argnums=2)(sino, angles, n)
+
+
 def test_reconstruction_batched_app_matches_loop_and_caches_angles():
     n, a, nd = 32, 16, 48
     img = shepp_logan(n)
@@ -217,11 +225,10 @@ def test_reconstruction_batched_app_matches_loop_and_caches_angles():
     sino = np.asarray(project_ref(img, angles, nd))
     msgs = [Msg(sino * (1 + 0.1 * i)) for i in range(3)]
     batched = ReconstructionApp("gridrec", n=n)
-    loop = ReconstructionApp("gridrec", n=n, batched=False, async_depth=0)
     out_b = batched.process(None, msgs)
-    out_l = loop.process(None, msgs)
+    expected = _gridrec_reference(msgs[-1], n)
     batched.sync()
-    np.testing.assert_allclose(np.asarray(out_b), np.asarray(out_l), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(out_b), np.asarray(expected), rtol=1e-5, atol=1e-6)
     # angles hoisted into the per-shape cache: same jnp array object reused
     assert list(batched._angles_cache) == [a]
     first = batched._angles(a)
@@ -237,16 +244,15 @@ def test_reconstruction_mixed_shapes_grouped():
     n = 24
     img = shepp_logan(n)
     frames = []
-    for a, nd in [(8, 32), (16, 32), (8, 32)]:
+    for i, (a, nd) in enumerate([(8, 32), (16, 32), (8, 32)]):
         angles = jnp.linspace(0, jnp.pi, a, endpoint=False)
-        frames.append(Msg(np.asarray(project_ref(img, angles, nd))))
+        frames.append(Msg(np.asarray(project_ref(img, angles, nd)) * (1 + 0.1 * i)))
     app = ReconstructionApp("gridrec", n=n)
     out = app.process(None, frames)
     app.sync()
     assert out.shape == (n, n)
     assert sorted(app._angles_cache) == [8, 16]
-    legacy = ReconstructionApp("gridrec", n=n, batched=False, async_depth=0)
-    expected = legacy.process(None, frames)
+    expected = _gridrec_reference(frames[-1], n)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected), rtol=1e-5, atol=1e-6)
 
 

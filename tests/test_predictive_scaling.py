@@ -1,38 +1,24 @@
 """Predictive vs reactive scaling on a seeded rate-step trace.
 
-A deterministic discrete-time queue simulator drives the *real* scaling
-policies (:class:`ForecastPolicy` vs :class:`ThresholdHysteresisPolicy`
-vs :class:`PIDScalingPolicy`) head-to-head on the same arrival trace: a
-Poisson-ish rate-step workload served at ``MU`` records/s/device, where
-every rescale pays a migration pause (service halts, arrivals pile up)
-that is fed back to the policies as ``MetricsSnapshot.state_migration_ms``
-— exactly the signal the forecast policy's migration gate consumes.
+A deterministic discrete-time queue simulator drives the real scaling
+policies (:class:`ForecastPolicy` vs :class:`ThresholdHysteresisPolicy` vs
+:class:`PIDScalingPolicy`) on the same arrival trace: a noisy rate step
+served at ``MU`` records/s/device, where every rescale pays a migration
+pause that is fed back to the policies as
+``MetricsSnapshot.state_migration_ms``, the signal the forecast policy's
+migration gate consumes.
 
 The controller mechanics mirror ``ElasticController``: cooldown gated
 before the policy is consulted, relative deltas in lease units, absolute
-targets rounded up on grow / down on shrink, clamped to
-``[MIN_DEVICES, MAX_DEVICES]``.
-
-Two costs are integrated over the run and both must favor the forecast
-policy for the acceptance bar of the predictive-scheduling PR:
-
-* ``lag_seconds``    — backlog integral (record-seconds of queueing): the
-  SLO side. Reactive policies only move after lag has accrued; the
-  forecast policy sizes from the arrival estimate.
-* ``device_seconds`` — devices held integral: the cost side. Hysteresis
-  holds surplus devices through its down-stability window; an absolute
-  forecast target releases them the tick the predicted load drops.
-
-Emits ``BENCH_predictive.json`` (CI bench-smoke artifact) and returns
-summary rows for ``benchmarks/run.py``.
+targets rounded up on grow and down on shrink, clamped to
+``[MIN_DEVICES, MAX_DEVICES]``. Two costs are integrated over the run and
+the forecast policy must win both: ``lag_seconds`` (the backlog integral,
+the SLO side) and ``device_seconds`` (devices held, the cost side).
 """
-from __future__ import annotations
-
-import argparse
-import json
 import math
-import os
 import random
+
+import pytest
 
 from repro.elastic import (
     ForecastPolicy,
@@ -40,8 +26,6 @@ from repro.elastic import (
     PIDScalingPolicy,
     ThresholdHysteresisPolicy,
 )
-
-OUT_DEFAULT = os.path.join(os.path.dirname(__file__), "BENCH_predictive.json")
 
 DT = 0.25               # simulator tick (s) == controller interval
 MU = 50.0               # true service rate (records/s/device)
@@ -51,7 +35,7 @@ COOLDOWN = 1.0          # ElasticConfig.cooldown
 MIGRATION_COST_FRAC = 0.1  # ElasticConfig.migration_cost_frac (amortization)
 SEED = 7
 
-#: (duration_s, arrival records/s) — calm, surge, partial relief, calm
+#: (duration_s, arrival records/s): calm, surge, partial relief, calm
 TRACE = ((20.0, 40.0), (30.0, 220.0), (25.0, 120.0), (25.0, 40.0))
 
 
@@ -81,19 +65,15 @@ def simulate(policy, trace, *, seed=SEED):
     """Run one policy over the trace; identical seeded arrival noise per
     policy, so the comparison is purely the scaling behavior."""
     rng = random.Random(seed)
-    total = sum(d for d, _ in trace)
-    n_ticks = int(round(total / DT))
+    n_ticks = int(round(sum(d for d, _ in trace) / DT))
     devices, lag = MIN_DEVICES, 0.0
     pause_left = 0.0
     migration_ms, migration_t = 0.0, 0.0
     last_action_t = -COOLDOWN
-    lag_seconds = device_seconds = peak_lag = 0.0
-    rescales = 0
-    timeline = []
-    # throughput gauge averaged since the last policy-visible snapshot —
-    # like a real bus gauge, and consistent with d(lag)/dt over the same
-    # window (instantaneous per-tick rates would break flow conservation
-    # across a migration pause)
+    lag_seconds = device_seconds = 0.0
+    # throughput gauge averaged since the last policy-visible snapshot, like
+    # a real bus gauge, and consistent with d(lag)/dt over the same window
+    # (per-tick rates would break flow conservation across a pause)
     served_acc, cap_acc, snap_t = 0.0, 0.0, -DT
 
     for i in range(n_ticks):
@@ -105,8 +85,6 @@ def simulate(policy, trace, *, seed=SEED):
         lag = lag + arrivals - served
         lag_seconds += lag * DT
         device_seconds += devices * DT
-        peak_lag = max(peak_lag, lag)
-        timeline.append([round(t, 2), round(lag, 1), devices])
         served_acc += served
         cap_acc += capacity
 
@@ -145,64 +123,18 @@ def simulate(policy, trace, *, seed=SEED):
         last_action_t = t
         pause_left = MIGRATION_S  # the rescale pause starts next tick
         migration_ms, migration_t = MIGRATION_S * 1e3, t
-        rescales += 1
 
-    return {
-        "lag_seconds": round(lag_seconds, 1),
-        "device_seconds": round(device_seconds, 1),
-        "peak_lag": round(peak_lag, 1),
-        "rescales": rescales,
-        "final_devices": devices,
-        "timeline": timeline,
-    }
+    return {"lag_seconds": lag_seconds, "device_seconds": device_seconds}
 
 
-def run(quick: bool = False, out: str = OUT_DEFAULT):
-    scale = 0.5 if quick else 1.0
+@pytest.mark.parametrize("scale", [0.5, 1.0])
+def test_forecast_policy_beats_reactive_policies_on_both_costs(scale):
+    """On the same seeded rate step, the forecast policy accrues less
+    backlog and holds fewer device-seconds than the better of threshold
+    hysteresis and PID, on the half-length and the full-length trace."""
     trace = tuple((d * scale, r) for d, r in TRACE)
     results = {name: simulate(p, trace) for name, p in _policies().items()}
-
-    fc = results["forecast"]
-    reactive_best = {
-        "lag_seconds": min(results[n]["lag_seconds"]
-                           for n in ("threshold", "pid")),
-        "device_seconds": min(results[n]["device_seconds"]
-                              for n in ("threshold", "pid")),
-    }
-    result = {
-        "trace": [list(s) for s in trace],
-        "mu_records_per_sec_per_device": MU,
-        "migration_pause_s": MIGRATION_S,
-        "seed": SEED,
-        "policies": results,
-        "forecast_wins_both": (
-            fc["lag_seconds"] < reactive_best["lag_seconds"]
-            and fc["device_seconds"] < reactive_best["device_seconds"]),
-    }
-    with open(out, "w") as f:
-        json.dump(result, f, indent=1)
-    rows = []
-    for name, r in results.items():
-        rows.append((f"predictive_{name}", 0.0,
-                     f"lag_s={r['lag_seconds']};dev_s={r['device_seconds']};"
-                     f"peak_lag={r['peak_lag']};rescales={r['rescales']}"))
-    rows.append(("predictive_forecast_wins_both", 0.0,
-                 f"wins={result['forecast_wins_both']}"))
-    return rows
-
-
-def main() -> None:
-    ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--quick", action="store_true", help="half-length trace")
-    ap.add_argument("--out", default=OUT_DEFAULT)
-    args = ap.parse_args()
-    rows = run(quick=args.quick, out=args.out)
-    for name, us, derived in rows:
-        print(f"{name},{us:.1f},{derived}")
-    with open(args.out) as f:
-        if not json.load(f)["forecast_wins_both"]:
-            raise SystemExit("forecast policy did not win on both cost axes")
-
-
-if __name__ == "__main__":
-    main()
+    fc = results.pop("forecast")
+    for cost in ("lag_seconds", "device_seconds"):
+        best_reactive = min(r[cost] for r in results.values())
+        assert fc[cost] < best_reactive, (cost, fc, results)

@@ -581,6 +581,57 @@ def test_detector_source_batches_through_the_ring():
         cluster.close()
 
 
+@pytest.mark.parametrize("plane,n_groups", [("log", 1), ("log", 4), ("shm", 1), ("shm", 4)])
+def test_detector_fan_out_every_group_receives_every_frame(plane, n_groups):
+    """Independent consumer groups drain one detector stream concurrently:
+    each receives every frame once, in order, with its payload, on either
+    data plane. The shm ring holds far fewer trains than the stream sends,
+    so the producer only gets through as every group commits."""
+    from repro.miniapps import DetectorSimSource, SourceConfig
+
+    n_msgs = 512
+    cluster = BrokerCluster(1)
+    if plane == "shm":
+        cluster.attach_transport(ShmTransport(slot_bytes=1 << 20, n_slots=4))
+    cluster.create_topic("frames", 1)
+    if plane == "shm":
+        cluster.transport.mount("frames")
+    # groups register before the stream starts: a registered group with no
+    # progress holds every slot, so no group can miss a frame
+    consumers = [Consumer(cluster, ConsumerGroup(cluster, f"g{i}", "frames"),
+                          f"m{i}", zero_copy=(plane == "shm"))
+                 for i in range(n_groups)]
+    src = DetectorSimSource(cluster, SourceConfig("frames", total_messages=n_msgs),
+                            ny=32, nx=32, n_cached=4, frames_per_batch=32)
+    seen = [[] for _ in consumers]
+
+    def drain(consumer, out):
+        deadline = time.monotonic() + 60
+        while len(out) < n_msgs and time.monotonic() < deadline:
+            for m in consumer.poll(max_records=128, timeout=0.5):
+                # read the payload before the commit lets its slot go
+                out.append((m.offset, np.array_equal(m.value, src._cache[m.offset % 4])))
+            consumer.commit()
+
+    threads = [threading.Thread(target=drain, args=(c, out), daemon=True)
+               for c, out in zip(consumers, seen)]
+    try:
+        for t in threads:
+            t.start()
+        src.start()
+        for t in threads:
+            t.join(timeout=90)
+        for out in seen:
+            assert [off for off, _ in out] == list(range(n_msgs))
+            assert all(ok for _, ok in out)
+        assert cluster.lost_records == 0
+    finally:
+        src.stop()
+        for c in consumers:
+            c.close()
+        cluster.close()
+
+
 def test_detector_source_hdf5_input(tmp_path):
     h5py = pytest.importorskip("h5py")
     from repro.miniapps import DetectorSimSource, SourceConfig
