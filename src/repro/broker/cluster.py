@@ -86,6 +86,12 @@ class Topic:
     ``leaders[p]`` names the node whose copy serves reads and assigns
     offsets. ``partitions`` keeps the seed-era shape (a list of logs, one
     per partition) by resolving to the current leader copies.
+
+    ``append_seq`` is the topic's append sequence: the cluster bumps it
+    after every acked append and after every change a blocked reader must
+    re-read for (placement, a group rebalance, close). A reader notes it
+    before a read pass and waits in :meth:`wait_append` only while it is
+    unchanged, so no append between the pass and the wait is missed.
     """
 
     def __init__(self, name: str, n_partitions: int, *,
@@ -98,6 +104,10 @@ class Topic:
         }
         self.leaders: dict[int, int] = {}
         self._make_log = make_log or (lambda p, base=0: PartitionLog(name, p, base_offset=base))
+        self.append_seq = 0
+        #: set once the topic is deleted: a waiting reader returns at once
+        self.closed = False
+        self._appended = threading.Condition()
 
     @property
     def n_partitions(self) -> int:
@@ -109,6 +119,22 @@ class Topic:
 
     def leader_log(self, partition: int) -> PartitionLog:
         return self.replicas[partition][self.leaders[partition]]
+
+    def bump(self) -> None:
+        """Advance the append sequence and wake every blocked reader."""
+        with self._appended:
+            self.append_seq += 1
+            self._appended.notify_all()
+
+    def close(self) -> None:
+        self.closed = True
+        self.bump()
+
+    def wait_append(self, seen: int, timeout: float) -> bool:
+        """Block until the append sequence has moved past ``seen`` (True)
+        or ``timeout`` seconds pass (False)."""
+        with self._appended:
+            return self._appended.wait_for(lambda: self.append_seq != seen, timeout)
 
     def holders(self, partition: int) -> list[int]:
         """Node ids holding a replica of ``partition`` (leader first)."""
@@ -235,6 +261,8 @@ class BrokerCluster:
                     self._groups.remove(ref)
                 else:
                     group.on_cluster_change()
+            for topic in self._topics.values():
+                topic.bump()  # blocked readers re-read against the new leaders
 
     def _alive_nodes(self) -> list[int]:
         return sorted(n for n, node in self._nodes.items() if node.alive)
@@ -273,6 +301,7 @@ class BrokerCluster:
                 t.leaders[p] = want[0]
                 if changed:
                     self._epoch[(topic, p)] = self._epoch.get((topic, p), 0) + 1
+                    t.bump()
 
     @property
     def n_nodes(self) -> int:
@@ -349,6 +378,14 @@ class BrokerCluster:
         ``delay_io`` fault — a degraded interconnect/disk)."""
         self.io_delay = max(float(seconds), 0.0)
 
+    def wake_readers(self, topic: str) -> None:
+        """Bump ``topic``'s append sequence so its blocked readers re-read
+        at once (a consumer group's membership changed)."""
+        with self._lock:
+            t = self._topics.get(topic)
+        if t is not None:
+            t.bump()
+
     def register_group(self, group) -> None:
         """Consumer groups register for post-failover generation bumps
         (held weakly; a closed group just drops out)."""
@@ -395,6 +432,7 @@ class BrokerCluster:
                 for logs in topic.replicas.values():
                     for log in logs.values():
                         log.close()
+                topic.close()
         if topic and transport is not None:
             transport.unmount(name)  # unlinks the shm segment
 
@@ -415,9 +453,11 @@ class BrokerCluster:
     def _check_available_locked(self, topic: str, partition: int) -> None:
         until = self._blackout.get((topic, partition))
         if until is not None:
-            if time.monotonic() < until:
+            now = time.monotonic()
+            if now < until:
                 raise BrokerUnavailable(
-                    f"{topic}[{partition}]: leader election in progress")
+                    f"{topic}[{partition}]: leader election in progress",
+                    retry_after=until - now)
             del self._blackout[(topic, partition)]
 
     def _resolve_locked(self, topic: str, partition: int):
@@ -457,7 +497,10 @@ class BrokerCluster:
             if offset >= 0:
                 for log in followers:  # acks=all: replicate before returning
                     log.append(record, timeout=remaining if deadline is not None else 30.0)
-            return offset
+            t = self._topics[topic]
+        if offset >= 0:
+            t.bump()  # acked: wake the readers blocked on this topic
+        return offset
 
     def append_many(self, topic: str, partition: int, records: list[Record],
                     *, deadline: float | None = None) -> list[int]:
@@ -486,7 +529,10 @@ class BrokerCluster:
             appended = [r for r, o in zip(records, offsets) if o >= 0]
             for log in followers:  # acks=all: replicate before returning
                 log.append_many(appended, timeout=timeout)
-            return offsets
+            t = self._topics[topic]
+        if appended:
+            t.bump()
+        return offsets
 
     def read(self, topic: str, partition: int, offset: int, max_records: int = 512,
              timeout: float = 0.0):

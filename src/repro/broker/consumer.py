@@ -11,10 +11,13 @@ Fault tolerance (docs/faults.md): a group registers with its cluster so a
 broker-node loss bumps the generation (members re-sync against promoted
 leaders on their next poll). ``poll`` treats :class:`BrokerUnavailable`
 from a failover blackout as "no data yet" — counted in ``retries``, never
-raised into an engine loop. An optional ``max_lag`` turns unbounded lag
-into graceful degradation: records beyond the bound are shed (skipped and
-counted in ``shed_records`` / the ``broker.shed_records`` gauge) so a slow
-consumer falls behind by a bounded amount instead of indefinitely.
+raised into an engine loop, and re-read when the blackout ends. A waiting
+``poll`` blocks on its topic's append sequence (``Topic.wait_append``),
+which every acked append, failover, rebalance and close advances. An
+optional ``max_lag`` turns unbounded lag into graceful degradation: records
+beyond the bound are shed (skipped and counted in ``shed_records`` / the
+``broker.shed_records`` gauge) so a slow consumer falls behind by a bounded
+amount instead of indefinitely.
 """
 from __future__ import annotations
 
@@ -96,12 +99,14 @@ class ConsumerGroup:
                 self._members.append(member_id)
                 self._members.sort()
                 self._generation += 1
+        self.cluster.wake_readers(self.topic)
 
     def leave(self, member_id: str) -> None:
         with self._lock:
             if member_id in self._members:
                 self._members.remove(member_id)
                 self._generation += 1
+        self.cluster.wake_readers(self.topic)
 
     def on_cluster_change(self) -> None:
         """Cluster callback after a node loss/failover: bump the generation
@@ -174,6 +179,13 @@ class Consumer:
         #: extra sleep before every poll — the ``slow_consumer`` fault knob
         #: (repro.faults); processing slows down, outputs stay identical
         self.injected_poll_delay = 0.0
+        #: how each poll's wait ended (its span's ``woke`` stat): ``none``
+        #: records were there, ``data`` one came while waiting, ``deadline``
+        self.wakes = {"none": 0, "data": 0, "deadline": 0}
+        #: seconds until the nearest failover blackout ends, noted by a
+        #: read pass that hit one
+        self._retry_in: float | None = None
+        self._closed = False
 
     def _refresh_assignment(self) -> None:
         if self._generation == self.group.generation:
@@ -216,59 +228,90 @@ class Consumer:
         """Up to ``max_records`` messages, waiting up to ``timeout`` seconds
         for the first; an empty list when none came."""
         with span("consumer.poll") as s:
-            out = self._poll(max_records, timeout)
-            s.set_metadata(records=len(out))
-        return out
-
-    def _poll(self, max_records: int, timeout: float) -> list[Message]:
-        if self.injected_poll_delay > 0:
-            time.sleep(self.injected_poll_delay)
-        self._refresh_assignment()
-        out: list[Message] = []
-        deadline = time.monotonic() + timeout
-        while not out:
-            for p, pos in list(self._positions.items()):
-                budget = max_records - len(out)
-                if budget <= 0:
-                    break
-                if self.max_lag is not None:
-                    pos = self._shed_locked(p, pos)
-                try:
-                    recs = self.cluster.read(self.group.topic, p, pos, budget)
-                except BrokerUnavailable:
-                    # leader election in flight — same as "nothing yet";
-                    # the next poll retries against the promoted leader
-                    self.retries += 1
-                    if self.metrics is not None:
-                        self.metrics.publish("broker.retries", self.retries,
-                                             member=self.member_id)
-                    continue
-                deser = self.deserialize
-                frame_value = self._frame_value
-                append = out.append
-                consumed = 0
-                for r in recs:
-                    v = r.value
-                    if deser and v[:1] == TAG_SLOT:
-                        val = frame_value(v)
-                        nb = getattr(val, "nbytes", None)
-                        consumed += int(nb) if nb is not None else r.size()
-                    else:
-                        val = _deserialize(v) if deser else v
-                        consumed += r.size()
-                    append(Message(p, r.offset, r.timestamp, val))
-                self.consumed_bytes += consumed
-                if recs:
-                    self._positions[p] = recs[-1].offset + 1
-            if out or time.monotonic() >= deadline:
-                break
-            time.sleep(0.002)
+            out, woke = self._wait(self._read_pass, max_records, timeout)
+            s.set_metadata(records=len(out), woke=woke)
         self.consumed_records += len(out)
         if out and self.metrics is not None:
             self.metrics.publish("consumer.records", self.consumed_records,
                                  member=self.member_id)
             self.metrics.publish("consumer.bytes", self.consumed_bytes,
                                  member=self.member_id)
+        return out
+
+    def _wait(self, read_pass: Callable[[int], list], max_records: int,
+              timeout: float) -> tuple[list, str]:
+        """Run ``read_pass`` until it returns records or ``timeout`` runs
+        out, blocking between passes on the topic's append sequence (not
+        a sleep): a record appended to any assigned partition wakes the
+        wait at once. Returns the records and how the wait ended —
+        ``none`` (records were there without waiting), ``data`` (they
+        came during the wait) or ``deadline`` (none came; also when the
+        topic or this consumer closed)."""
+        if self.injected_poll_delay > 0:
+            time.sleep(self.injected_poll_delay)
+        topic = self.cluster.topic(self.group.topic)
+        deadline = time.monotonic() + timeout
+        out: list = []
+        woke = "none"
+        while not (topic.closed or self._closed):
+            self._refresh_assignment()
+            seen = topic.append_seq  # before the pass: no append is missed
+            self._retry_in = None
+            out = read_pass(max_records)
+            wait = deadline - time.monotonic()
+            if out or wait <= 0:
+                break
+            if self._retry_in is not None:  # re-read when the blackout ends
+                wait = min(wait, self._retry_in)
+            topic.wait_append(seen, wait)
+            woke = "data"
+        if not out:
+            woke = "deadline"
+        self.wakes[woke] += 1
+        return out, woke
+
+    def _read(self, p: int, pos: int, max_records: int) -> list:
+        """One partition's records from ``pos``. A failover blackout reads
+        as nothing yet: counted in ``retries``, and its end noted so the
+        wait re-reads against the promoted leader then."""
+        if self.max_lag is not None:
+            pos = self._shed_locked(p, pos)
+        try:
+            return self.cluster.read(self.group.topic, p, pos, max_records)
+        except BrokerUnavailable as e:
+            self.retries += 1
+            if self.metrics is not None:
+                self.metrics.publish("broker.retries", self.retries,
+                                     member=self.member_id)
+            if e.retry_after is not None:
+                self._retry_in = (e.retry_after if self._retry_in is None
+                                  else min(self._retry_in, e.retry_after))
+            return []
+
+    def _read_pass(self, max_records: int) -> list[Message]:
+        out: list[Message] = []
+        deser = self.deserialize
+        frame_value = self._frame_value
+        append = out.append
+        for p, pos in list(self._positions.items()):
+            budget = max_records - len(out)
+            if budget <= 0:
+                break
+            recs = self._read(p, pos, budget)
+            consumed = 0
+            for r in recs:
+                v = r.value
+                if deser and v[:1] == TAG_SLOT:
+                    val = frame_value(v)
+                    nb = getattr(val, "nbytes", None)
+                    consumed += int(nb) if nb is not None else r.size()
+                else:
+                    val = _deserialize(v) if deser else v
+                    consumed += r.size()
+                append(Message(p, r.offset, r.timestamp, val))
+            self.consumed_bytes += consumed
+            if recs:
+                self._positions[p] = recs[-1].offset + 1
         return out
 
     # ---- shm frames (repro.transport) ---------------------------------------
@@ -311,64 +354,50 @@ class Consumer:
         if zero_copy is None:
             zero_copy = self.zero_copy
         with span("consumer.poll") as s:
-            out = self._poll_batch(max_records, timeout, zero_copy)
-            s.set_metadata(records=sum(len(b) for b in out))
-        return out
-
-    def _poll_batch(self, max_records: int, timeout: float,
-                    zero_copy: bool) -> list[PolledBatch]:
-        if self.injected_poll_delay > 0:
-            time.sleep(self.injected_poll_delay)
-        self._refresh_assignment()
-        out: list[PolledBatch] = []
-        deadline = time.monotonic() + timeout
-        while not out:
-            for p, pos in list(self._positions.items()):
-                if self.max_lag is not None:
-                    pos = self._shed_locked(p, pos)
-                try:
-                    recs = self.cluster.read(self.group.topic, p, pos, max_records)
-                except BrokerUnavailable:
-                    self.retries += 1
-                    continue
-                i = 0
-                while i < len(recs):
-                    r = recs[i]
-                    if self.deserialize and r.value[:1] == TAG_SLOT:
-                        name, slot, epoch, _ = decode_slot_record(r.value)
-                        rows, offsets, stamps = [], [], []
-                        while i < len(recs) and recs[i].value[:1] == TAG_SLOT:
-                            n2, s2, e2, row2 = decode_slot_record(recs[i].value)
-                            if (n2, s2, e2) != (name, slot, epoch):
-                                break
-                            rows.append(row2)
-                            offsets.append(recs[i].offset)
-                            stamps.append(recs[i].timestamp)
-                            i += 1
-                        saved, self.zero_copy = self.zero_copy, zero_copy
-                        try:
-                            frame = self._decoded_frame(name, slot, epoch)
-                        finally:
-                            self.zero_copy = saved
-                        values = [frame.values[row] for row in rows]
-                        out.append(PolledBatch(p, offsets, stamps, values, frame))
-                        self.consumed_bytes += sum(
-                            int(getattr(v, "nbytes", 0)) for v in values)
-                    else:
-                        val = _deserialize(r.value) if self.deserialize else r.value
-                        out.append(PolledBatch(p, [r.offset], [r.timestamp], [val]))
-                        self.consumed_bytes += r.size()
-                        i += 1
-                if recs:
-                    self._positions[p] = recs[-1].offset + 1
-            if out or time.monotonic() >= deadline:
-                break
-            time.sleep(0.002)
-        n = sum(len(b) for b in out)
+            out, woke = self._wait(
+                lambda n: self._batch_pass(n, zero_copy), max_records, timeout)
+            n = sum(len(b) for b in out)
+            s.set_metadata(records=n, woke=woke)
         self.consumed_records += n
         if out and self.metrics is not None:
             self.metrics.publish("consumer.records", self.consumed_records,
                                  member=self.member_id)
+        return out
+
+    def _batch_pass(self, max_records: int, zero_copy: bool) -> list[PolledBatch]:
+        out: list[PolledBatch] = []
+        for p, pos in list(self._positions.items()):
+            recs = self._read(p, pos, max_records)
+            i = 0
+            while i < len(recs):
+                r = recs[i]
+                if self.deserialize and r.value[:1] == TAG_SLOT:
+                    name, slot, epoch, _ = decode_slot_record(r.value)
+                    rows, offsets, stamps = [], [], []
+                    while i < len(recs) and recs[i].value[:1] == TAG_SLOT:
+                        n2, s2, e2, row2 = decode_slot_record(recs[i].value)
+                        if (n2, s2, e2) != (name, slot, epoch):
+                            break
+                        rows.append(row2)
+                        offsets.append(recs[i].offset)
+                        stamps.append(recs[i].timestamp)
+                        i += 1
+                    saved, self.zero_copy = self.zero_copy, zero_copy
+                    try:
+                        frame = self._decoded_frame(name, slot, epoch)
+                    finally:
+                        self.zero_copy = saved
+                    values = [frame.values[row] for row in rows]
+                    out.append(PolledBatch(p, offsets, stamps, values, frame))
+                    self.consumed_bytes += sum(
+                        int(getattr(v, "nbytes", 0)) for v in values)
+                else:
+                    val = _deserialize(r.value) if self.deserialize else r.value
+                    out.append(PolledBatch(p, [r.offset], [r.timestamp], [val]))
+                    self.consumed_bytes += r.size()
+                    i += 1
+            if recs:
+                self._positions[p] = recs[-1].offset + 1
         return out
 
     def positions(self) -> dict[int, int]:
@@ -391,5 +420,7 @@ class Consumer:
         self._frames.clear()
 
     def close(self) -> None:
+        """Leave the group; a poll waiting in another thread returns []."""
+        self._closed = True
         self.release_frames()
         self.group.leave(self.member_id)

@@ -18,7 +18,12 @@ class BrokerUnavailable(BrokerError):
     """The partition has no reachable leader right now (a failover is in
     flight, or placement changed mid-operation). Transient by contract:
     callers retry with jittered backoff; ``Producer.send`` and
-    ``Consumer.poll`` do this built-in."""
+    ``Consumer.poll`` do this built-in. ``retry_after`` is the seconds left
+    in a failover blackout, when the broker knows them."""
+
+    def __init__(self, message: str = "", *, retry_after: float | None = None):
+        super().__init__(message)
+        self.retry_after = retry_after
 
 
 class BrokerTimeout(BrokerError):

@@ -30,8 +30,8 @@ from repro.streaming.dispatch import LatencyWindow
 from repro.streaming.rate_control import PIDRateController
 
 #: longest poll while a record-wise window holds records and the device is
-#: still busy: the consumer's own wait step, so the window closes within one
-#: step of the device draining
+#: still busy, so the window closes within one step of the device draining;
+#: a record arriving meanwhile ends the poll at once
 DRAIN_POLL_S = 0.002
 
 
@@ -222,11 +222,10 @@ class MicroBatchStream:
             except BaseException as e:  # surfaced on await/stop
                 self._error = e
                 break
-            if n == 0:
-                if self.metrics is not None:
-                    self._publish_idle()
-                with span("engine.idle"):
-                    time.sleep(0.01)
+            # an empty window already waited out its interval in the
+            # consumer's blocking poll: the next window opens at once
+            if n == 0 and self.metrics is not None:
+                self._publish_idle()
 
     # ---- control ------------------------------------------------------------
 

@@ -200,6 +200,197 @@ def test_committed_offsets_survive_failover():
     assert [m.offset for m in replay] == list(range(10, 20))
 
 
+# ---------------------------------------------------------------------------
+# a waiting poll blocks on the topic's append sequence, not on a sleep
+# ---------------------------------------------------------------------------
+
+
+class _SpanLog:
+    """Stands in for ``repro.elastic.metrics.span``: keeps each span's name
+    and final stats, as a profiler trace would."""
+
+    def __init__(self):
+        self.ended: list[tuple[str, dict]] = []
+
+    def __call__(self, name, **stats):
+        log = self
+
+        class _Span:
+            def __enter__(self):
+                return self
+
+            def set_metadata(self, **more):
+                stats.update(more)
+
+            def __exit__(self, *exc):
+                log.ended.append((name, stats))
+
+        return _Span()
+
+
+def _waiting_consumer(n_parts, **kw):
+    cluster = BrokerCluster(kw.pop("nodes", 1))
+    cluster.create_topic("t", n_parts, **kw)
+    return cluster, Consumer(cluster, ConsumerGroup(cluster, "g", "t"), "m",
+                             deserialize=False)
+
+
+def _poll_in_thread(call):
+    """Run ``call`` in a thread; the returned dict gets its result (or
+    error) and the monotonic instant it returned."""
+    done: dict = {}
+
+    def run():
+        try:
+            done["out"] = call()
+        except BaseException as e:  # surfaced by the test's asserts
+            done["error"] = e
+        done["at"] = time.monotonic()
+
+    t = threading.Thread(target=run)
+    t.start()
+    done["thread"] = t
+    return done
+
+
+@pytest.mark.parametrize("n_parts,part", [(1, 0), (3, 0), (3, 1), (3, 2)],
+                         ids=["one", "three-p0", "three-p1", "three-p2"])
+@pytest.mark.parametrize("batched", [False, True], ids=["poll", "poll_batch"])
+def test_waiting_poll_wakes_on_an_append(n_parts, part, batched):
+    cluster, c = _waiting_consumer(n_parts)
+    assert c.assignment == list(range(n_parts))
+    poll = c.poll_batch if batched else c.poll
+    done = _poll_in_thread(lambda: poll(timeout=1.0))
+    time.sleep(0.1)
+    sent = time.monotonic()
+    cluster.append("t", part, Record(b"x"))
+    done["thread"].join(2)
+    assert "error" not in done
+    assert len(done["out"]) == 1 and done["out"][0].partition == part
+    assert done["at"] - sent < 0.05  # the poll step alone would add ~1 ms
+    assert c.wakes == {"none": 0, "data": 1, "deadline": 0}
+
+
+@pytest.mark.parametrize("n_parts", [1, 3])
+def test_idle_poll_does_not_spin(n_parts, monkeypatch):
+    cluster, c = _waiting_consumer(n_parts)
+    reads = []
+    real = cluster.read
+    monkeypatch.setattr(cluster, "read", lambda *a, **kw: reads.append(a) or real(*a, **kw))
+    t0 = time.monotonic()
+    assert c.poll(timeout=0.3) == []
+    assert time.monotonic() - t0 >= 0.3
+    # one pass before the wait and one at its deadline; 2 ms steps made ~150
+    assert len(reads) <= 3 * n_parts
+    assert c.wakes["deadline"] == 1
+
+
+@pytest.mark.parametrize("how", ["consumer_close", "topic_close", "failover"])
+def test_a_wait_ends_cleanly_on_close_or_failover(how):
+    cluster, c = _waiting_consumer(1, nodes=2, replication_factor=2)
+    done = _poll_in_thread(lambda: c.poll(timeout=2.0))
+    time.sleep(0.1)
+    start = time.monotonic()
+    if how == "consumer_close":
+        c.close()
+    elif how == "topic_close":
+        cluster.close()
+    else:
+        cluster.fail_node(cluster.topic("t").leaders[0], blackout=0.1)
+        Producer(cluster, "t", serializer="raw").send(b"after")
+    done["thread"].join(3)
+    assert "error" not in done, done.get("error")
+    assert done["at"] - start < 1.0  # well before the 2 s deadline
+    want = [b"after"] if how == "failover" else []
+    assert [m.value for m in done["out"]] == want
+
+
+def test_poll_with_no_timeout_never_blocks():
+    cluster, c = _waiting_consumer(3)
+    t0 = time.monotonic()
+    assert c.poll(timeout=0) == []
+    assert c.poll_batch(timeout=0) == []
+    assert time.monotonic() - t0 < 0.05
+    cluster.append("t", 1, Record(b"x"))
+    assert [m.value for m in c.poll(timeout=0)] == [b"x"]
+    assert c.wakes == {"none": 1, "data": 0, "deadline": 2}
+
+
+def test_poll_span_says_how_the_wait_ended(monkeypatch):
+    import repro.broker.consumer as consumer_mod
+
+    spans = _SpanLog()
+    monkeypatch.setattr(consumer_mod, "span", spans)
+    cluster, c = _waiting_consumer(1)
+    assert c.poll(timeout=0.05) == []
+    done = _poll_in_thread(lambda: c.poll(timeout=1.0))
+    time.sleep(0.1)
+    cluster.append("t", 0, Record(b"x"))
+    done["thread"].join(2)
+    cluster.append("t", 0, Record(b"y"))
+    assert [m.value for m in c.poll(timeout=1.0)] == [b"y"]
+    assert spans.ended == [
+        ("consumer.poll", {"records": 0, "woke": "deadline"}),
+        ("consumer.poll", {"records": 1, "woke": "data"}),
+        ("consumer.poll", {"records": 1, "woke": "none"}),
+    ]
+
+
+def test_an_append_between_the_read_pass_and_the_wait_is_not_missed(monkeypatch):
+    """The append lands after the pass read nothing and before the wait
+    begins: the wait must see the sequence moved and re-read at once."""
+    cluster, c = _waiting_consumer(1)
+    real = cluster.read
+
+    def read_then_append(*a, **kw):
+        recs = real(*a, **kw)
+        if not recs and cluster.topic("t").partitions[0].high_watermark == 0:
+            cluster.append("t", 0, Record(b"late"))
+        return recs
+
+    monkeypatch.setattr(cluster, "read", read_then_append)
+    t0 = time.monotonic()
+    assert [m.value for m in c.poll(timeout=2.0)] == [b"late"]
+    assert time.monotonic() - t0 < 0.5
+
+
+def test_concurrent_appends_all_come_out_once_in_order():
+    """More appending threads than cores, with a short switch interval:
+    every record comes out once and in partition order, and no poll waits
+    out its deadline while appends keep coming."""
+    import os
+    import sys
+
+    n_threads = (os.cpu_count() or 4) + 2
+    per_thread = 200
+    cluster, c = _waiting_consumer(3)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        def produce(i):
+            for k in range(per_thread):
+                cluster.append("t", (i + k) % 3, Record(b"%d:%d" % (i, k)))
+
+        threads = [threading.Thread(target=produce, args=(i,)) for i in range(n_threads)]
+        for t in threads:
+            t.start()
+        got = []
+        deadline = time.monotonic() + 30
+        while len(got) < n_threads * per_thread and time.monotonic() < deadline:
+            got.extend(c.poll(max_records=64, timeout=2.0))
+        for t in threads:
+            t.join(5)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(interval)
+    assert len(got) == n_threads * per_thread
+    assert len({m.value for m in got}) == len(got)
+    for p in range(3):
+        offsets = [m.offset for m in got if m.partition == p]
+        assert offsets == list(range(len(offsets)))
+    assert c.wakes["deadline"] == 0
+
+
 if st is not None:
 
     @given(st.lists(st.integers(0, 255), min_size=0, max_size=200))

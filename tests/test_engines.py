@@ -160,6 +160,55 @@ def test_records_held_while_busy_come_out_as_one_batch(drains):
         assert stream.stats.idle_closes == 0
 
 
+def test_record_after_an_empty_window_is_processed_at_once(monkeypatch):
+    """An empty window ends in the consumer's blocking poll and the next
+    opens at once: no idle sleep (and no ``engine.idle`` span) lies between
+    them, and the append itself wakes the poll."""
+    import repro.broker.consumer as consumer_mod
+    import repro.engines.microbatch as microbatch_mod
+
+    spans = []
+
+    def span(name, **stats):
+        class _Span:
+            def __enter__(self):
+                return self
+
+            def set_metadata(self, **more):
+                stats.update(more)
+
+            def __exit__(self, *exc):
+                spans.append((name, stats, time.monotonic()))
+
+        return _Span()
+
+    monkeypatch.setattr(consumer_mod, "span", span)
+    monkeypatch.setattr(microbatch_mod, "span", span)
+    app = _RecordWise()
+    cluster, stream, prod = _microbatch(app.process, 0.05)
+    stream.start()
+    try:
+        deadline = time.monotonic() + 5
+        while not any(n == "engine.collect" and st["records"] == 0
+                      for n, st, _ in spans):
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+        sent = time.monotonic()
+        prod.send(np.array([1.0]))
+        stream.await_batches(1, timeout=5)
+        time.sleep(0.15)  # more empty windows
+    finally:
+        stream.stop()
+        cluster.close()
+    assert app.batches == [[1.0]]
+    assert app.started[0] - sent < 0.03
+    names = {n for n, _, _ in spans}
+    assert "engine.idle" not in names
+    assert sum(n == "engine.collect" and st["records"] == 0 for n, st, _ in spans) >= 3
+    [woke] = [st["woke"] for n, st, _ in spans if n == "consumer.poll" and st["records"]]
+    assert woke == "data"
+
+
 def test_max_batch_records_still_caps_a_record_wise_window():
     app = _RecordWise()
     cluster, stream, prod = _microbatch(app.process, 2.0, max_batch_records=2)
